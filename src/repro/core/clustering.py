@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.metrics import StreamStat
+from repro.core.observability import span
 from repro.core.providers import Provider
 from repro.core.simclock import Clock
 from repro.core.task import Task, arg_signature, execute_task, stable_fn_key
@@ -72,8 +73,10 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
 
     Returns ``(results, exec_s, n_fused, fallback)``: `results` is a
     per-task list of ``(ok, value, error)`` aligned with `tasks`, `exec_s`
-    the measured wall seconds of the execution (the fused device call, or
-    the sum of per-task executions on the fallback path), `n_fused` how
+    the measured host wall seconds of the execution on the calling
+    thread (stacking, the copies to and from the device, the fused call
+    with any compile, and `device_get`; or the sum of per-task executions
+    on the fallback path) — not device time — `n_fused` how
     many tasks went through the batched call (0 when it was not used), and
     `fallback` the exception that made a bundle of two or more tasks
     degrade to per-task execution (None when it did not).  Fault checks
@@ -85,6 +88,15 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
 
     `vmapped_cache` maps ``(stable_fn_key, in_axes)`` to the compiled
     ``jit(vmap(fn))`` so steady-state bundles pay zero retrace.
+
+    Each phase is a span on the profiler's clock (`observability.span`):
+    ``bundle.stack`` (resolving and stacking the arguments; an eager
+    ``jnp.stack`` of device inputs), ``bundle.h2d`` (the copy of a host
+    stack to the device), ``bundle.call`` (the fused call, with a compile
+    on a cache miss), ``bundle.wait`` (until its output is ready),
+    ``bundle.d2h`` (`device_get`), ``bundle.split`` (per-task results),
+    ``bundle.single`` (a one-task bundle's eager body) and
+    ``bundle.fallback`` (the per-task re-run after a fused-path error).
     """
     n = len(tasks)
     results: list = [None] * n
@@ -103,40 +115,54 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
     if len(live) == 1:
         i = live[0]
         t0 = perf_counter()
-        results[i] = _execute_unchecked(tasks[i])
+        with span("bundle.single"):
+            results[i] = _execute_unchecked(tasks[i])
         return results, perf_counter() - t0, 0, None
     t0 = perf_counter()
     try:
-        arg_lists = [resolve_args(tasks[i]) for i in live]
-        n_args = len(arg_lists[0])
-        # args identical across the bundle broadcast (in_axes=None)
-        # instead of being stacked — no 256x weight copies
-        shared = [all(al[i] is arg_lists[0][i] for al in arg_lists)
-                  for i in range(n_args)]
-        in_axes = tuple(None if s else 0 for s in shared)
-
-        def stack(items):
-            if all(isinstance(a, np.ndarray) for a in items):
-                return jnp.asarray(np.stack(items))  # one h2d transfer
-            return jnp.stack(items)
-
-        stacked = [arg_lists[0][i] if shared[i]
-                   else stack([al[i] for al in arg_lists])
-                   for i in range(n_args)]
+        with span("bundle.stack"):
+            arg_lists = [resolve_args(tasks[i]) for i in live]
+            n_args = len(arg_lists[0])
+            # args identical across the bundle broadcast (in_axes=None)
+            # instead of being stacked — no 256x weight copies
+            shared = [all(al[i] is arg_lists[0][i] for al in arg_lists)
+                      for i in range(n_args)]
+            in_axes = tuple(None if s else 0 for s in shared)
+            stacked = [arg_lists[0][i] if shared[i]
+                       else _stack([al[i] for al in arg_lists])
+                       for i in range(n_args)]
         vkey = (stable_fn_key(fn), in_axes)
         vfn = vmapped_cache.get(vkey)
         if vfn is None:
             vfn = jax.jit(jax.vmap(fn, in_axes=in_axes))
             vmapped_cache[vkey] = vfn
-        out = jax.device_get(vfn(*stacked))
-        for i, r in zip(live, _split_result(out, len(live))):
-            results[i] = (True, r, None)
+        with span("bundle.call"):
+            out = vfn(*stacked)
+        with span("bundle.wait"):
+            jax.block_until_ready(out)
+        with span("bundle.d2h"):
+            out = jax.device_get(out)
+        with span("bundle.split"):
+            for i, r in zip(live, _split_result(out, len(live))):
+                results[i] = (True, r, None)
         return results, perf_counter() - t0, len(live), None
     except Exception as err:  # noqa: BLE001 — degrade, counted by callers
         t0 = perf_counter()
-        for i in live:
-            results[i] = _execute_unchecked(tasks[i])
+        with span("bundle.fallback"):
+            for i in live:
+                results[i] = _execute_unchecked(tasks[i])
         return results, perf_counter() - t0, 0, err
+
+
+def _stack(items: list):
+    """One bundle argument stacked along a new leading axis: host arrays
+    with `np.stack` and one copy to the device (``bundle.h2d``), device
+    arrays with an eager `jnp.stack`."""
+    if all(isinstance(a, np.ndarray) for a in items):
+        host = np.stack(items)
+        with span("bundle.h2d"):
+            return jnp.asarray(host)
+    return jnp.stack(items)
 
 
 def _execute_unchecked(task):

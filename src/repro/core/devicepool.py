@@ -8,8 +8,8 @@ launch.  `DeviceExecutorPool` closes that loop behind the same
 `vmap_signature` accumulate into bundles, and each bundle executes as ONE
 jitted+vmapped device call — one launch for K tiny tasks — on a dedicated
 dispatcher thread.  Per-task completions fan back through
-`Clock.post_release`, and the measured bundle time is attributed per task
-into bounded `StreamStat`s.
+`Clock.post_release`, each with its share of the bundle's measured time,
+and the pool keeps exact counters (tasks, bundles, dispatcher seconds).
 
 Wiring is identical to the other pools::
 
@@ -34,7 +34,15 @@ time, and `foreach(window=)` / federation operate above the pool seam.
 Threading contract (DESIGN.md §10): `submit` and `flush` run on the clock
 thread; dispatcher threads touch only the bundle queue, the vmapped-jit
 cache, and `post`/`release`; completions and all counters run back on the
-clock thread.
+clock thread.  The dispatcher reads the clock once per bundle, when it
+takes the bundle off the queue, and hands that time back in the posted
+completion (`Span.dequeued`): it never touches a span itself.
+
+Layer spans on the profiler's clock (`observability.span`): clock thread
+``pool.submit`` (each `submit` and `flush`) and ``pool.complete`` (one per
+bundle, around the per-task completions it fans out); dispatcher thread
+``pool.bundle`` around one bundle, with `execute_bundle`'s ``bundle.*``
+phases inside it.
 """
 from __future__ import annotations
 
@@ -45,7 +53,7 @@ from time import perf_counter
 from typing import Any, Callable, Optional
 
 from repro.core.clustering import execute_bundle, resolve_args, vmap_signature
-from repro.core.metrics import StreamStat
+from repro.core.observability import span
 from repro.core.realpool import _require_threadsafe_clock
 from repro.core.simclock import Clock
 
@@ -68,10 +76,11 @@ class DeviceExecutorPool:
     Measured, not priced: `done(ok, value, err, io_s, run_s)` receives the
     staging seconds observed for that task and its share of the bundle's
     measured execution time (`bundle_s / K`).  `device_s` accumulates the
-    total seconds the dispatcher spent inside device execution — the
-    numerator of the benchmark's "device-bound, not dispatcher-bound"
-    fraction (benchmarks/device_batching.py).  `fallbacks` counts bundles
-    whose fused call failed and ran per task instead, and
+    dispatcher thread's host wall seconds inside `execute_bundle` —
+    stacking, the copies to and from the device, the call (with any
+    compile) and `device_get` — *not* device time (a profiler trace
+    gives that); `io_s` the staging seconds of every task.  `fallbacks`
+    counts bundles whose fused call failed and ran per task instead, and
     `last_fallback` keeps the newest such error's repr: on the device path
     a compile refusal or an out-of-memory shows up there, not as silent
     slowness.
@@ -110,10 +119,8 @@ class DeviceExecutorPool:
         self.fused_tasks = 0
         self.fallbacks = 0
         self.last_fallback: Optional[str] = None
-        self.device_s = 0.0                  # measured execution seconds
-        self.io_stat = StreamStat(cap=256)   # measured staging s per task
-        self.run_stat = StreamStat(cap=256)  # attributed execution s per task
-        self.bundle_stat = StreamStat(cap=256)  # tasks per bundle
+        self.device_s = 0.0   # dispatcher host s inside execute_bundle
+        self.io_s = 0.0       # staging s, every task
 
     def size(self) -> int:
         return len(self._threads)
@@ -131,32 +138,34 @@ class DeviceExecutorPool:
         the `linger` flush; others ship immediately as singletons.
         `done(ok, value, err, io_s, run_s)` is called back on the clock
         thread, once per task."""
-        if self._shutdown:
-            raise RuntimeError(f"pool {self.name!r} is shut down")
-        self.clock.hold()
-        if task.vmap_key is None or task.fn is None:
-            self._q.put([(task, done, stage)])
-            return
-        key = (task.vmap_key, vmap_signature(task.fn, resolve_args(task)))
-        bucket = self._pending.get(key)
-        if bucket is None:
-            self._pending[key] = bucket = []
-        bucket.append((task, done, stage))
-        if len(bucket) >= self.max_bundle:
-            del self._pending[key]
-            self._q.put(bucket)
-        elif not self._flush_scheduled:
-            self._flush_scheduled = True
-            self.clock.schedule(self.linger, self.flush)
+        with span("pool.submit"):
+            if self._shutdown:
+                raise RuntimeError(f"pool {self.name!r} is shut down")
+            self.clock.hold()
+            if task.vmap_key is None or task.fn is None:
+                self._q.put([(task, done, stage)])
+                return
+            key = (task.vmap_key, vmap_signature(task.fn, resolve_args(task)))
+            bucket = self._pending.get(key)
+            if bucket is None:
+                self._pending[key] = bucket = []
+            bucket.append((task, done, stage))
+            if len(bucket) >= self.max_bundle:
+                del self._pending[key]
+                self._q.put(bucket)
+            elif not self._flush_scheduled:
+                self._flush_scheduled = True
+                self.clock.schedule(self.linger, self.flush)
 
     def flush(self) -> None:
         """Ship every pending bucket to the dispatcher (clock thread)."""
-        self._flush_scheduled = False
-        if not self._pending:
-            return
-        pending, self._pending = self._pending, {}
-        for bundle in pending.values():
-            self._q.put(bundle)
+        with span("pool.submit"):
+            self._flush_scheduled = False
+            if not self._pending:
+                return
+            pending, self._pending = self._pending, {}
+            for bundle in pending.values():
+                self._q.put(bundle)
 
     def shutdown(self, wait: bool = True) -> None:
         """Stop the dispatcher threads (after their queued bundles) and
@@ -177,84 +186,92 @@ class DeviceExecutorPool:
             bundle = self._q.get()
             if bundle is _STOP:
                 return
-            self._run_bundle(bundle)
+            self._run_bundle(bundle, self.clock.now())
 
-    def _run_bundle(self, bundle: list) -> None:
-        # real staging copies first, per task, inside the measured io time
-        # (exactly where the simulated path adds priced staging I/O);
-        # a staging failure fails that task and drops it from the batch
-        io_ss = [0.0] * len(bundle)
-        staged_err: dict[int, BaseException] = {}
-        for i, (_task, _done, stage) in enumerate(bundle):
-            if stage is None:
-                continue
-            t0 = perf_counter()
-            try:
-                stage()
-            except BaseException as err:  # noqa: BLE001 — staging failure
-                staged_err[i] = err
-            io_ss[i] = perf_counter() - t0
-        live = [i for i in range(len(bundle)) if i not in staged_err]
-        tasks = [bundle[i][0] for i in live]
-        if tasks:
-            results, exec_s, n_fused, fallback = execute_bundle(
-                tasks[0].fn, tasks, self._vmapped_cache)
-        else:
-            results, exec_s, n_fused, fallback = [], 0.0, 0, None
-        # measured bundle time attributed evenly across the tasks that
-        # actually executed; staged-failure tasks carry only their io time
-        out: list = [None] * len(bundle)
-        run_ss = [0.0] * len(bundle)
-        per_task = exec_s / max(1, len(live))
-        for i, err in staged_err.items():
-            out[i] = (False, None, err)
-        for i, res in zip(live, results):
-            out[i] = res
-            run_ss[i] = per_task
-        # one posted completion per bundle; the post lands before any
-        # hold token is returned, so the loop can never observe
-        # "no holds, no events" mid-handoff
-        self.clock.post(partial(self._complete_bundle, bundle, out,
-                                io_ss, run_ss, exec_s, n_fused, fallback))
-        for _ in bundle:
-            self.clock.release()
+    def _run_bundle(self, bundle: list, dequeued: float) -> None:
+        with span("pool.bundle"):
+            # real staging copies first, per task, inside the measured io
+            # time (exactly where the simulated path adds priced staging
+            # I/O); a staging failure fails that task and drops it from
+            # the batch
+            io_ss = [0.0] * len(bundle)
+            staged_err: dict[int, BaseException] = {}
+            for i, (_task, _done, stage) in enumerate(bundle):
+                if stage is None:
+                    continue
+                t0 = perf_counter()
+                try:
+                    stage()
+                except BaseException as err:  # noqa: BLE001 — staging
+                    staged_err[i] = err
+                io_ss[i] = perf_counter() - t0
+            live = [i for i in range(len(bundle)) if i not in staged_err]
+            tasks = [bundle[i][0] for i in live]
+            if tasks:
+                results, exec_s, n_fused, fallback = execute_bundle(
+                    tasks[0].fn, tasks, self._vmapped_cache)
+            else:
+                results, exec_s, n_fused, fallback = [], 0.0, 0, None
+            # measured bundle time attributed evenly across the tasks that
+            # actually executed; staged-failure tasks carry only their io
+            out: list = [None] * len(bundle)
+            run_ss = [0.0] * len(bundle)
+            per_task = exec_s / max(1, len(live))
+            for i, err in staged_err.items():
+                out[i] = (False, None, err)
+            for i, res in zip(live, results):
+                out[i] = res
+                run_ss[i] = per_task
+            # one posted completion per bundle; the post lands before any
+            # hold token is returned, so the loop can never observe
+            # "no holds, no events" mid-handoff
+            self.clock.post(partial(self._complete_bundle, bundle, out,
+                                    io_ss, run_ss, exec_s, n_fused,
+                                    fallback, dequeued))
+            for _ in bundle:
+                self.clock.release()
 
     # -- back on the clock thread ----------------------------------------
     def _complete_bundle(self, bundle, out, io_ss, run_ss, exec_s,
-                         n_fused, fallback) -> None:
-        now = self.clock.now()
-        self.bundles_run += 1
-        self.device_s += exec_s
-        self.bundle_stat.observe(now, len(bundle))
-        self.fused_tasks += n_fused
-        if fallback is not None:
-            self.fallbacks += 1
-            self.last_fallback = repr(fallback)
-        if self.tracer is not None and n_fused:
-            self.tracer.event("bundle_fused", now, n_fused)
-        tr = self.tracer
-        for (task, done, _stage), (ok, v, err), io_s, run_s in zip(
-                bundle, out, io_ss, run_ss):
-            self.tasks_run += 1
-            self.io_stat.observe(now, io_s)
-            self.run_stat.observe(now, run_s)
-            if tr is not None and not ok:
-                # worker-level failure signal (DESIGN.md §13), same kind
-                # the thread/process pools emit
-                tr.event("worker_error", now)
-            done(ok, v, err, io_s, run_s)
+                         n_fused, fallback, dequeued) -> None:
+        with span("pool.complete"):
+            now = self.clock.now()
+            self.bundles_run += 1
+            self.device_s += exec_s
+            self.fused_tasks += n_fused
+            if fallback is not None:
+                self.fallbacks += 1
+                self.last_fallback = repr(fallback)
+            if self.tracer is not None and n_fused:
+                self.tracer.event("bundle_fused", now, n_fused)
+            tr = self.tracer
+            self.io_s += sum(io_ss)
+            for (task, done, _stage), (ok, v, err), io_s, run_s in zip(
+                    bundle, out, io_ss, run_ss):
+                self.tasks_run += 1
+                if tr is not None:
+                    sp = getattr(task, "span", None)
+                    if sp is not None:
+                        sp.dequeued = dequeued
+                    if not ok:
+                        # worker-level failure signal (DESIGN.md §13),
+                        # same kind the thread/process pools emit
+                        tr.event("worker_error", now)
+                done(ok, v, err, io_s, run_s)
 
     def metrics(self) -> dict:
-        """Bounded snapshot — safe at any task count."""
+        """Exact counters — safe at any task count.  `device_s` is the
+        dispatcher's host seconds inside `execute_bundle`, not device
+        time; `io_s` the staging seconds of every task."""
         return {
             "dispatchers": self.size(),
             "tasks_run": self.tasks_run,
             "bundles_run": self.bundles_run,
+            "bundle_mean": (self.tasks_run / self.bundles_run
+                            if self.bundles_run else 0.0),
             "fused_tasks": self.fused_tasks,
             "fallbacks": self.fallbacks,
             "last_fallback": self.last_fallback,
             "device_s": self.device_s,
-            "bundle_size": self.bundle_stat.summary(),
-            "io_s": self.io_stat.summary(),
-            "run_s": self.run_stat.summary(),
+            "io_s": self.io_s,
         }

@@ -27,6 +27,7 @@ from functools import partial
 from repro.core.datastore import inputs_of
 from repro.core.faults import FaultInjector, RetryPolicy, TaskFailure
 from repro.core.futures import DataFuture, when_all
+from repro.core.observability import span
 from repro.core.provenance import VDC, InvocationRecord
 from repro.core.providers import (BatchSchedulerProvider, ClusteringProvider,
                                   FalkonProvider, LocalProvider, Provider,
@@ -265,101 +266,102 @@ class Engine:
         layer — a `DataObject`, an iterable of them, or a callable mapping
         the call args to either (see `DataLayer`, DESIGN.md §7).
         """
-        args = args or []
-        out = DataFuture(name=name)
-        if key is None:
-            # dataflow-stable keys are only needed for restart-log lookups
-            # and journaling; skip the fingerprint hash on the hot path
-            # otherwise, and in summary-provenance mode (no stored records
-            # reference the key) skip even the counter suffix
-            if self.journal is not None:
-                # the store's primary key is (wf, key): duplicate content
-                # keys get a deterministic occurrence suffix
-                key = self.journal.unique_key(task_key(name, args))
-            elif self.restart_log is not None:
-                key = task_key(name, args)
-            elif self._prov_records:
-                key = f"{name}#{self.tasks_submitted}"
-            else:
-                key = name
-        out.name = key
+        with span("engine.submit"):
+            args = args or []
+            out = DataFuture(name=name)
+            if key is None:
+                # dataflow-stable keys are only needed for restart-log lookups
+                # and journaling; skip the fingerprint hash on the hot path
+                # otherwise, and in summary-provenance mode (no stored records
+                # reference the key) skip even the counter suffix
+                if self.journal is not None:
+                    # the store's primary key is (wf, key): duplicate content
+                    # keys get a deterministic occurrence suffix
+                    key = self.journal.unique_key(task_key(name, args))
+                elif self.restart_log is not None:
+                    key = task_key(name, args)
+                elif self._prov_records:
+                    key = f"{name}#{self.tasks_submitted}"
+                else:
+                    key = name
+            out.name = key
 
-        # restart log: datasets already produced are marked available and
-        # their producing tasks are NOT re-run (paper §3.12)
-        if self.restart_log is not None:
-            hit, value = self.restart_log.lookup(key)
-            if hit:
-                self.tasks_restored += 1
-                out.set(value)
-                return out
+            # restart log: datasets already produced are marked available and
+            # their producing tasks are NOT re-run (paper §3.12)
+            if self.restart_log is not None:
+                hit, value = self.restart_log.lookup(key)
+                if hit:
+                    self.tasks_restored += 1
+                    out.set(value)
+                    return out
 
-        # Procedure.__call__ already normalizes to a tuple — trust it and
-        # skip re-normalizing on the per-task hot path; a callable spec
-        # receives the call args, as on the Procedure path
-        if type(inputs) is not tuple:
-            inputs = inputs_of(inputs, *args) if inputs is not None else ()
-        task = Task(name, fn, args, out, duration, app,
-                    self.retry_policy.max_retries, durable, key,
-                    inputs=inputs)
-        task.created_time = self.clock.now()
-        j = self.journal
-        if j is not None and j.full:
-            # terminal durability records completions only — the
-            # non-terminal transitions never leave the clock thread
-            j.task_submitted(key)
-        task.vmap_key = vmap_key
-        tr = self.tracer
-        if tr is not None:
-            # the sampling decision (Tracer.task_created) is inlined — a
-            # counter bump and one modulus for the overwhelming non-sampled
-            # majority; ready_t/path0 are stamped in _ready (dependent
-            # tasks) or just below (dependency-free tasks), never in
-            # Task.__init__, so the tracing-off hot path skips the slots
-            tr.tasks_seen = seen = tr.tasks_seen + 1
-            task.span = (tr._new_span(task, task.created_time,
-                                      self.shard_id)
-                         if (seen - 1) % tr._k == 0 else None)
-        if self.fault_injector is not None:
-            inj = self.fault_injector
-
-            def chk(t):
-                s = t.site
-                inj.check(t.name, t.host, t.attempt,
-                          s.name if s is not None else "")
-
-            if getattr(inj, "timed", False):
-                # fail-slow rules: the Falkon sim path pre-evaluates the
-                # check at dispatch so TaskFailure.latency can set the
-                # failed attempt's service time
-                chk.timed = True
-            task.fault_check = chk
-        self.tasks_submitted += 1
-        # dependency scan without per-task garbage: at frontier scale
-        # (10^6 in-flight tasks) the list + closure the seed allocated
-        # here were ~40% of per-task graph memory; `partial` carries the
-        # task reference in one small object instead
-        first = None
-        nfuts = 0
-        for a in args:
-            if isinstance(a, DataFuture):
-                nfuts += 1
-                if first is None:
-                    first = a
-        if nfuts == 0:
+            # Procedure.__call__ already normalizes to a tuple — trust it and
+            # skip re-normalizing on the per-task hot path; a callable spec
+            # receives the call args, as on the Procedure path
+            if type(inputs) is not tuple:
+                inputs = inputs_of(inputs, *args) if inputs is not None else ()
+            task = Task(name, fn, args, out, duration, app,
+                        self.retry_policy.max_retries, durable, key,
+                        inputs=inputs)
+            task.created_time = self.clock.now()
+            j = self.journal
+            if j is not None and j.full:
+                # terminal durability records completions only — the
+                # non-terminal transitions never leave the clock thread
+                j.task_submitted(key)
+            task.vmap_key = vmap_key
+            tr = self.tracer
             if tr is not None:
-                task.path0 = -task.created_time
-            if (duration is None and fn is not None
-                    and self.duration_predictor is not None):
-                task.duration = self.duration_predictor.predict_duration(
-                    fn, args)
-            self._dispatch(task)
-        elif nfuts == 1:
-            # single dependency (serial chains): skip the when_all counter
-            first.on_done(partial(self._ready, task))
-        else:
-            when_all((a for a in args if isinstance(a, DataFuture)),
-                     partial(self._ready, task))
-        return out
+                # the sampling decision (Tracer.task_created) is inlined — a
+                # counter bump and one modulus for the overwhelming non-sampled
+                # majority; ready_t/path0 are stamped in _ready (dependent
+                # tasks) or just below (dependency-free tasks), never in
+                # Task.__init__, so the tracing-off hot path skips the slots
+                tr.tasks_seen = seen = tr.tasks_seen + 1
+                task.span = (tr._new_span(task, task.created_time,
+                                          self.shard_id)
+                             if (seen - 1) % tr._k == 0 else None)
+            if self.fault_injector is not None:
+                inj = self.fault_injector
+
+                def chk(t):
+                    s = t.site
+                    inj.check(t.name, t.host, t.attempt,
+                              s.name if s is not None else "")
+
+                if getattr(inj, "timed", False):
+                    # fail-slow rules: the Falkon sim path pre-evaluates the
+                    # check at dispatch so TaskFailure.latency can set the
+                    # failed attempt's service time
+                    chk.timed = True
+                task.fault_check = chk
+            self.tasks_submitted += 1
+            # dependency scan without per-task garbage: at frontier scale
+            # (10^6 in-flight tasks) the list + closure the seed allocated
+            # here were ~40% of per-task graph memory; `partial` carries the
+            # task reference in one small object instead
+            first = None
+            nfuts = 0
+            for a in args:
+                if isinstance(a, DataFuture):
+                    nfuts += 1
+                    if first is None:
+                        first = a
+            if nfuts == 0:
+                if tr is not None:
+                    task.path0 = -task.created_time
+                if (duration is None and fn is not None
+                        and self.duration_predictor is not None):
+                    task.duration = self.duration_predictor.predict_duration(
+                        fn, args)
+                self._dispatch(task)
+            elif nfuts == 1:
+                # single dependency (serial chains): skip the when_all counter
+                first.on_done(partial(self._ready, task))
+            else:
+                when_all((a for a in args if isinstance(a, DataFuture)),
+                         partial(self._ready, task))
+            return out
 
     # -- submit-side backpressure (DESIGN.md §9) -----------------------
     def inflight(self) -> int:
@@ -413,62 +415,69 @@ class Engine:
 
     # ------------------------------------------------------------------
     def _ready(self, task: Task, _f: DataFuture | None = None):
-        tr = self.tracer
-        if tr is None:
-            for a in task.args:
-                if isinstance(a, DataFuture) and a.failed:
-                    task.output.set_error(
-                        TaskFailure(f"upstream failure for {task.name}"))
-                    self.tasks_failed += 1
-                    if self.journal is not None:
-                        self.journal.task_failed(task.key, "upstream failure")
-                    task.args = ()
-                    return
-        else:
-            # single pass over the args: the upstream-failure check merged
-            # with the O(1)/task critical-path propagation — path up to
-            # this task's start is the max over its parents' path values,
-            # read here *before* the args are cleared below (DESIGN.md §12)
-            p0 = 0.0
-            for a in task.args:
-                if type(a) is DataFuture:
-                    if a.failed:
+        with span("engine.ready"):
+            tr = self.tracer
+            if tr is None:
+                for a in task.args:
+                    if isinstance(a, DataFuture) and a.failed:
                         task.output.set_error(
                             TaskFailure(f"upstream failure for {task.name}"))
                         self.tasks_failed += 1
-                        tr.task_done(task, self.clock.now(), "failed")
                         if self.journal is not None:
                             self.journal.task_failed(task.key,
                                                      "upstream failure")
                         task.args = ()
                         return
-                    p = a.path
-                    if p > p0:
-                        p0 = p
-            now = self.clock.now()
-            # path0 encodes (parent path - ready time): completion adds
-            # `now` back, so the done-path costs one addition per task
-            task.path0 = p0 - now
-            sp = task.span
-            if sp is not None:
-                sp.ready = now
-        if task.fn is None and task.vmap_key is None:
-            # pure-sim task: the argument values are never read again, so
-            # drop them now — in a streaming (windowed) expansion this is
-            # what lets a resolved upstream chain be freed while its
-            # dependents are still queued (DESIGN.md §9 GC contract)
-            task.args = ()
-        elif (task.duration is None and task.fn is not None
-                and self.duration_predictor is not None):
-            # future-fed tasks are priced here, when the argument shapes
-            # are known; the predictor's signature cache makes this a
-            # dict probe for every task after the first per signature
-            task.duration = self.duration_predictor.predict_duration(
-                task.fn, [a.get() if isinstance(a, DataFuture) else a
-                          for a in task.args])
-        self._dispatch(task)
+            else:
+                # single pass over the args: the upstream-failure check merged
+                # with the O(1)/task critical-path propagation — path up to
+                # this task's start is the max over its parents' path
+                # values, read here *before* the args are cleared below
+                # (DESIGN.md §12)
+                p0 = 0.0
+                for a in task.args:
+                    if type(a) is DataFuture:
+                        if a.failed:
+                            task.output.set_error(TaskFailure(
+                                f"upstream failure for {task.name}"))
+                            self.tasks_failed += 1
+                            tr.task_done(task, self.clock.now(), "failed")
+                            if self.journal is not None:
+                                self.journal.task_failed(task.key,
+                                                         "upstream failure")
+                            task.args = ()
+                            return
+                        p = a.path
+                        if p > p0:
+                            p0 = p
+                now = self.clock.now()
+                # path0 encodes (parent path - ready time): completion adds
+                # `now` back, so the done-path costs one addition per task
+                task.path0 = p0 - now
+                sp = task.span
+                if sp is not None:
+                    sp.ready = now
+            if task.fn is None and task.vmap_key is None:
+                # pure-sim task: the argument values are never read again, so
+                # drop them now — in a streaming (windowed) expansion this is
+                # what lets a resolved upstream chain be freed while its
+                # dependents are still queued (DESIGN.md §9 GC contract)
+                task.args = ()
+            elif (task.duration is None and task.fn is not None
+                    and self.duration_predictor is not None):
+                # future-fed tasks are priced here, when the argument shapes
+                # are known; the predictor's signature cache makes this a
+                # dict probe for every task after the first per signature
+                task.duration = self.duration_predictor.predict_duration(
+                    task.fn, [a.get() if isinstance(a, DataFuture) else a
+                              for a in task.args])
+            self._dispatch(task)
 
     def _dispatch(self, task: Task, exclude_site: str | None = None):
+        # no span of its own: called from submit / _ready / _done, it is
+        # part of theirs; the drain and a retry's re-dispatch, the calls
+        # that stand alone, take ``engine.dispatch`` (8 spans a task at
+        # most, observability.span)
         j = self.journal
         if j is not None and j.full:
             j.task_ready(task.key)
@@ -540,17 +549,18 @@ class Engine:
         task and head-of-line-blocked apps whose site had no room.  The
         per-app buckets make the pass O(apps + placed): an app whose sites
         are full is skipped at its bucket head, its backlog untouched."""
-        self._drain_scheduled = False
-        pending = self._pending
-        if self.fair_share and len(pending._buckets) > 1:
-            self._drain_fair(pending)
-            return
-        for app, bucket in pending.buckets():
-            while bucket:
-                task, excl = bucket[0]
-                if not self._place(task, excl):
-                    break              # app blocked; leave its backlog be
-                pending.pop_head(app)
+        with span("engine.dispatch"):
+            self._drain_scheduled = False
+            pending = self._pending
+            if self.fair_share and len(pending._buckets) > 1:
+                self._drain_fair(pending)
+                return
+            for app, bucket in pending.buckets():
+                while bucket:
+                    task, excl = bucket[0]
+                    if not self._place(task, excl):
+                        break              # app blocked; leave its backlog be
+                    pending.pop_head(app)
 
     def _drain_fair(self, pending: ReadyQueue):
         """Stride-scheduled drain (DESIGN.md §15): each placement goes to
@@ -591,110 +601,119 @@ class Engine:
         self._fair_vt = vt
 
     def _done(self, task: Task, ok: bool, value, err):
-        site = task.site
-        now = self.clock.now()
-        site.outstanding -= 1
-        if self.balancer.duration_aware:
-            # clamp: float drift must never leave a phantom backlog
-            site.outstanding_work = max(
-                0.0, site.outstanding_work - sim_duration(task))
-        if self._pending:
-            if not self._drain_scheduled:
-                self._drain_scheduled = True
-                self.clock.schedule(0.0, self._drain_pending)
-        elif self._federation is not None:
-            # shard starving: no held backlog left — let the federation's
-            # stealer consider migrating work here (flag-guarded, O(1))
-            self._federation.notify_idle(self)
-        fed = self._federation
-        if fed is not None and fed._bp_waiters:
-            fed._wake_backpressure()
-        if self._bp_waiters:
-            # not elif: a workflow driven over one *shard* of a federation
-            # registers its waiters here, and they must still fire
-            self._wake_backpressure()
-        if ok:
-            site.on_success(now - task.submit_time)
-            self.tasks_completed += 1
-            h = self.health
-            if h is not None:
-                # inlined HealthMonitor.task_finished (§13 hot-path
-                # contract): error windows come from Site.stats counter
-                # deltas on the monitor's tick, and the straggler registry
-                # self-prunes — a success pays only the sampling stride
-                if h._dur_skip:
-                    h._dur_skip -= 1
-                else:
-                    h.sample_turnaround(task, site, now)
-            self._record(task, "ok")
-            if self.restart_log is not None and task.durable:
-                self.restart_log.append(task.key, value)
-            if self.journal is not None:
-                self.journal.task_done(task.key, value)
+        with span("engine.done"):
+            site = task.site
+            now = self.clock.now()
+            site.outstanding -= 1
+            if self.balancer.duration_aware:
+                # clamp: float drift must never leave a phantom backlog
+                site.outstanding_work = max(
+                    0.0, site.outstanding_work - sim_duration(task))
+            if self._pending:
+                if not self._drain_scheduled:
+                    self._drain_scheduled = True
+                    self.clock.schedule(0.0, self._drain_pending)
+            elif self._federation is not None:
+                # shard starving: no held backlog left — let the federation's
+                # stealer consider migrating work here (flag-guarded, O(1))
+                self._federation.notify_idle(self)
+            fed = self._federation
+            if fed is not None and fed._bp_waiters:
+                fed._wake_backpressure()
+            if self._bp_waiters:
+                # not elif: a workflow driven over one *shard* of a federation
+                # registers its waiters here, and they must still fire
+                self._wake_backpressure()
+            if ok:
+                site.on_success(now - task.submit_time)
+                self.tasks_completed += 1
+                h = self.health
+                if h is not None:
+                    # inlined HealthMonitor.task_finished (§13 hot-path
+                    # contract): error windows come from Site.stats counter
+                    # deltas on the monitor's tick, and the straggler registry
+                    # self-prunes — a success pays only the sampling stride
+                    if h._dur_skip:
+                        h._dur_skip -= 1
+                    else:
+                        h.sample_turnaround(task, site, now)
+                self._record(task, "ok")
+                if self.restart_log is not None and task.durable:
+                    self.restart_log.append(task.key, value)
+                if self.journal is not None:
+                    self.journal.task_done(task.key, value)
+                tr = self.tracer
+                if tr is not None:
+                    # inlined Tracer.task_done: stamp the output's
+                    # critical-path length before the set() fires
+                    # downstream callbacks (dependents read it in _ready)
+                    tr.tasks_done += 1
+                    path = task.path0 + now
+                    if path > tr.critical_path_s:
+                        tr.critical_path_s = path
+                    task.output.path = path
+                    sp = task.span
+                    if sp is not None:
+                        tr._close_span(sp, task, now, "ok")
+                task.args = ()             # resolved chains must be GC-able: a
+                task.fault_check = None    # retained record must not pin its
+                task.output.set(value)     # upstream futures (DESIGN.md §9)
+                return
+            # failure path (§3.12)
+            if getattr(err, "kind", None) == "revoked":
+                # administrative drain revocation (DESIGN.md §13): a drained
+                # service handed the still-queued task back — re-place it on
+                # another site without charging a retry or denting the score
+                self.tasks_revoked += 1
+                if self.health is not None:
+                    self.health.task_revoked(task)
+                if self.tracer is not None:
+                    self.tracer.event("revoked", now)
+                if self.journal is not None and self.journal.full:
+                    self.journal.task_revoked(task.key)
+                self._dispatch(task, exclude_site=site.name)
+                return
+            site.on_failure()
+            # no monitor hook on failure: error windows come from Site.stats
+            # counter deltas, and the straggler registry entry (if any) tracks
+            # the live task across its retries (HealthMonitor.task_finished)
+            failures = task.site_failures
+            if failures is None:
+                failures = task.site_failures = {}
+            failures[site.name] = failures.get(site.name, 0) + 1
+            self._record(task,
+                         "retried" if task.retries_left > 0 else "failed",
+                         error=str(err))
             tr = self.tracer
             if tr is not None:
-                # inlined Tracer.task_done: stamp the output's critical-path
-                # length before the set() fires downstream callbacks
-                # (dependents read it in _ready)
-                tr.tasks_done += 1
-                path = task.path0 + now
-                if path > tr.critical_path_s:
-                    tr.critical_path_s = path
-                task.output.path = path
-                sp = task.span
-                if sp is not None:
-                    tr._close_span(sp, task, now, "ok")
-            task.args = ()             # resolved chains must be GC-able: a
-            task.fault_check = None    # retained record must not pin its
-            task.output.set(value)     # upstream futures (DESIGN.md §9)
-            return
-        # failure path (§3.12)
-        if getattr(err, "kind", None) == "revoked":
-            # administrative drain revocation (DESIGN.md §13): a drained
-            # service handed the still-queued task back — re-place it on
-            # another site without charging a retry or denting the score
-            self.tasks_revoked += 1
-            if self.health is not None:
-                self.health.task_revoked(task)
-            if self.tracer is not None:
-                self.tracer.event("revoked", now)
-            if self.journal is not None and self.journal.full:
-                self.journal.task_revoked(task.key)
-            self._dispatch(task, exclude_site=site.name)
-            return
-        site.on_failure()
-        # no monitor hook on failure: error windows come from Site.stats
-        # counter deltas, and the straggler registry entry (if any) tracks
-        # the live task across its retries (HealthMonitor.task_finished)
-        failures = task.site_failures
-        if failures is None:
-            failures = task.site_failures = {}
-        failures[site.name] = failures.get(site.name, 0) + 1
-        self._record(task, "retried" if task.retries_left > 0 else "failed",
-                     error=str(err))
-        tr = self.tracer
-        if tr is not None:
-            status = "retried" if task.retries_left > 0 else "failed"
-            path = tr.task_done(task, now, status)
-            if status == "failed":
-                task.output.path = path
-        if task.retries_left <= 0:
-            self.tasks_failed += 1
-            if self.journal is not None:
-                self.journal.task_failed(task.key, str(err))
-            task.args = ()
-            task.fault_check = None
-            task.output.set_error(err or TaskFailure(f"{task.name} failed"))
-            return
-        task.retries_left -= 1
-        task.attempt += 1
-        exclude = None
-        kind = getattr(err, "kind", "transient")
-        if (kind == "site" or failures[site.name]
-                >= self.retry_policy.site_fail_threshold):
-            exclude = site.name  # reschedule at a different site
-        self.clock.schedule(self.retry_policy.backoff,
-                            lambda: self._dispatch(task, exclude_site=exclude))
+                status = "retried" if task.retries_left > 0 else "failed"
+                path = tr.task_done(task, now, status)
+                if status == "failed":
+                    task.output.path = path
+            if task.retries_left <= 0:
+                self.tasks_failed += 1
+                if self.journal is not None:
+                    self.journal.task_failed(task.key, str(err))
+                task.args = ()
+                task.fault_check = None
+                task.output.set_error(
+                    err or TaskFailure(f"{task.name} failed"))
+                return
+            task.retries_left -= 1
+            task.attempt += 1
+            exclude = None
+            kind = getattr(err, "kind", "transient")
+            if (kind == "site" or failures[site.name]
+                    >= self.retry_policy.site_fail_threshold):
+                exclude = site.name  # reschedule at a different site
+            self.clock.schedule(self.retry_policy.backoff,
+                                partial(self._redispatch, task, exclude))
+
+    def _redispatch(self, task: Task, exclude_site: str | None) -> None:
+        """A retry's dispatch once its backoff has passed (a clock event
+        of its own, so it takes its own span)."""
+        with span("engine.dispatch"):
+            self._dispatch(task, exclude_site)
 
     def _record(self, task: Task, status: str, error: str = ""):
         now = self.clock.now()

@@ -27,7 +27,7 @@ from typing import Callable, Optional
 
 from repro.core.faults import TaskFailure
 from repro.core.metrics import StreamStat
-from repro.core.observability import BoundedLog, Tracer
+from repro.core.observability import BoundedLog, Tracer, span
 from repro.core.simclock import Clock
 from repro.core.task import execute_task, sim_duration
 
@@ -249,13 +249,16 @@ class FalkonService:
     # dispatch
     # ------------------------------------------------------------------
     def submit(self, task, when_done: Callable):
-        task._falkon_done = when_done
-        task.submit_time = self.clock.now()
-        self.queue.append(task)
-        if len(self.queue) > self.peak_queue:
-            self.peak_queue = len(self.queue)
-        self._maybe_grow()
-        self._pump()
+        # one span for the task's whole way through the service: queue,
+        # DRP check and the pump that hands it on (observability.span)
+        with span("falkon.dispatch"):
+            task._falkon_done = when_done
+            task.submit_time = self.clock.now()
+            self.queue.append(task)
+            if len(self.queue) > self.peak_queue:
+                self.peak_queue = len(self.queue)
+            self._maybe_grow()
+            self._dispatch_queued()
 
     def _push_idle(self, e: Executor) -> None:
         """Add to the idle pool unless a live entry already exists — an
@@ -299,6 +302,16 @@ class FalkonService:
         return found
 
     def _pump(self):
+        """Dispatch queued tasks to idle executors (after a completion,
+        an allocation, a lapsed suspension): one ``falkon.pump`` span per
+        call that finds work, none for an empty queue."""
+        if not self.queue:
+            self._dispatch_queued()      # records the queue length only
+            return
+        with span("falkon.pump"):
+            self._dispatch_queued()
+
+    def _dispatch_queued(self):
         queue = self.queue
         self.queue_stat.observe(self.clock.now(), len(queue))
         if self.trace:
@@ -446,56 +459,58 @@ class FalkonService:
         """Shared post-execution bookkeeping for both paths.  `busy_s` is
         the measured service time on the real path; the simulated path
         derives it from the scheduled start/end."""
-        end = self.clock.now()
-        if self.trace:
-            e.task_log.append((start, end))
-            self.tracer.exec_span(self.name, e.host, start, end, task.name)
-        dl = self.data_layer
-        if dl is not None and task.inputs:
-            dl.release_inputs(e, task)
-        self.tasks_finished += 1
-        e.busy = False
-        e.idle_since = end
-        e.busy_time += busy_s if busy_s is not None else max(0.0, end - start)
-        if ok:
-            e.consec_failures = 0
-            e.tasks_done += 1
-        else:
-            e.consec_failures += 1
-            if e.consec_failures >= self.cfg.host_fail_threshold:
-                # paper §3.12: suspend faulty host, reschedule elsewhere
-                e.suspended_until = end + self.cfg.host_suspend_time
+        with span("falkon.complete"):
+            end = self.clock.now()
+            if self.trace:
+                e.task_log.append((start, end))
+                self.tracer.exec_span(self.name, e.host, start, end, task.name)
+            dl = self.data_layer
+            if dl is not None and task.inputs:
+                dl.release_inputs(e, task)
+            self.tasks_finished += 1
+            e.busy = False
+            e.idle_since = end
+            e.busy_time += (busy_s if busy_s is not None
+                            else max(0.0, end - start))
+            if ok:
                 e.consec_failures = 0
-        if self.health is not None:
-            # per-executor windowed error rates (DESIGN.md §13); the
-            # monitor may extend `suspended_until` beyond the
-            # consecutive-failure heuristic above
-            self.health.on_executor(self, e, ok, end)
-        next_local = None
-        if e.local_q and end < e.suspended_until:
-            # suspended host: hand its affinity queue back to the
-            # service so other holders (or cold spillover) take it
-            self._parked -= len(e.local_q)
-            self.queue.extendleft(reversed(e.local_q))
-            e.local_q.clear()
-            e.local_work = 0.0
-        elif e.local_q:
-            next_local = e.local_q.popleft()
-            e.local_work -= sim_duration(next_local)
-            self._parked -= 1
-        if next_local is None:
-            self._push_idle(e)
-        # break the task -> callback -> task reference cycle so
-        # completed tasks are freed by refcounting, not the cycle GC
-        callback = task._falkon_done
-        task._falkon_done = None
-        if next_local is not None:
-            # affinity queue drains first: the executor keeps running
-            # tasks whose inputs it already holds (data diffusion)
-            self._dispatch(e, next_local)
-        callback(ok, value, err)
-        self._maybe_shrink()
-        self._pump()
+                e.tasks_done += 1
+            else:
+                e.consec_failures += 1
+                if e.consec_failures >= self.cfg.host_fail_threshold:
+                    # paper §3.12: suspend faulty host, reschedule elsewhere
+                    e.suspended_until = end + self.cfg.host_suspend_time
+                    e.consec_failures = 0
+            if self.health is not None:
+                # per-executor windowed error rates (DESIGN.md §13); the
+                # monitor may extend `suspended_until` beyond the
+                # consecutive-failure heuristic above
+                self.health.on_executor(self, e, ok, end)
+            next_local = None
+            if e.local_q and end < e.suspended_until:
+                # suspended host: hand its affinity queue back to the
+                # service so other holders (or cold spillover) take it
+                self._parked -= len(e.local_q)
+                self.queue.extendleft(reversed(e.local_q))
+                e.local_q.clear()
+                e.local_work = 0.0
+            elif e.local_q:
+                next_local = e.local_q.popleft()
+                e.local_work -= sim_duration(next_local)
+                self._parked -= 1
+            if next_local is None:
+                self._push_idle(e)
+            # break the task -> callback -> task reference cycle so
+            # completed tasks are freed by refcounting, not the cycle GC
+            callback = task._falkon_done
+            task._falkon_done = None
+            if next_local is not None:
+                # affinity queue drains first: the executor keeps running
+                # tasks whose inputs it already holds (data diffusion)
+                self._dispatch(e, next_local)
+            callback(ok, value, err)
+            self._maybe_shrink()
+            self._pump()
 
     def drain_queued(self) -> int:
         """Revoke every queued-but-not-running task (global queue plus

@@ -34,6 +34,30 @@ time.  This module is the one place that question is answered from:
                         standard report schema; `tools/trace_view.py`
                         renders it (and validates chrome traces) from the
                         command line.
+  * `span(name)`      — a host span on the JAX profiler's clock
+                        (`jax.profiler.TraceAnnotation`), shared with the
+                        device trace and recorded only while a profiler
+                        trace is active.  The `Tracer` above is the
+                        per-task lifecycle on the run's own clock (virtual
+                        under `SimClock`); these spans are its real-time
+                        half, one per call of a layer, so a profiler trace
+                        shows which layer holds the host.  Each span's
+                        *self* time (minus the spans nested in it on the
+                        same thread) is that layer's cost.  Names are a
+                        contract with the benchmark (`chipbench/`) and
+                        PERF.md — clock thread: ``engine.submit``,
+                        ``engine.ready``, ``engine.dispatch``,
+                        ``engine.done``, ``falkon.dispatch``,
+                        ``falkon.pump``, ``falkon.complete``,
+                        ``pool.submit``, ``pool.complete``; dispatcher
+                        thread: ``pool.bundle`` around one bundle and, in
+                        `clustering.execute_bundle`, ``bundle.stack``,
+                        ``bundle.h2d``, ``bundle.call``, ``bundle.wait``,
+                        ``bundle.d2h``, ``bundle.split``,
+                        ``bundle.single``, ``bundle.fallback``.  With the
+                        profiler off a span costs one annotation object
+                        (0.4–1 µs), so a layer takes one span per call,
+                        never one per task of a multi-task call.
 
 Hot-path contract: with no tracer attached every hook is a single
 `is not None` test.  With a tracer attached, a *non-sampled* task costs
@@ -48,13 +72,19 @@ from __future__ import annotations
 import json
 from typing import Any, Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.health import RollingStat
 from repro.core.metrics import StreamStat, percentile_of
 
 __all__ = [
     "BoundedLog", "Span", "Tracer", "MetricsRegistry", "RunReport",
-    "build_report",
+    "build_report", "span",
 ]
+
+# ``with span("engine.submit"): ...`` — a host span on the profiler's clock
+# (module docstring); an alias, so the call costs no extra Python frame
+span = TraceAnnotation
 
 
 class BoundedLog:
@@ -121,7 +151,10 @@ class Span:
     `RealClock`): `created` (submitted to the engine), `ready` (argument
     futures resolved; equals `created` for dependency-free tasks),
     `submitted` (handed to a site provider), `started` (body begins,
-    after dispatch overhead + staging), `ended` (completion observed).
+    after dispatch overhead + staging; on a real pool, the hand-off to
+    the pool), `dequeued` (a `DeviceExecutorPool` dispatcher took the
+    task's bundle off its queue; 0.0 on other paths), `ended`
+    (completion observed).
     `io_s` is the staging (stage-wait) time, `weight` the number of tasks
     this sampled span statistically represents (the sampling stride at
     creation), `shard` the federation shard (None outside a federation).
@@ -129,7 +162,7 @@ class Span:
 
     __slots__ = ("span_id", "name", "app", "shard", "site", "host",
                  "status", "attempt", "weight", "created", "ready",
-                 "submitted", "started", "ended", "io_s")
+                 "submitted", "started", "dequeued", "ended", "io_s")
 
     def __init__(self, span_id: str, name: str, app: str | None,
                  shard: int | None, created: float, weight: int):
@@ -146,6 +179,7 @@ class Span:
         self.ready = created
         self.submitted = 0.0
         self.started = 0.0
+        self.dequeued = 0.0
         self.ended = 0.0
         self.io_s = 0.0
 
@@ -161,8 +195,8 @@ class Span:
             "status": self.status, "attempt": self.attempt,
             "weight": self.weight, "created": self.created,
             "ready": self.ready, "submitted": self.submitted,
-            "started": self.started, "ended": self.ended,
-            "io_s": self.io_s,
+            "started": self.started, "dequeued": self.dequeued,
+            "ended": self.ended, "io_s": self.io_s,
         }
 
     def __repr__(self):

@@ -21,7 +21,7 @@ import pytest
 
 from repro.core import (DRPConfig, DataLayer, DeviceExecutorPool,
                         Engine, FalkonConfig, FalkonProvider, FalkonService,
-                        RealClock, SharedStore, SimClock, Workflow)
+                        RealClock, SharedStore, SimClock, Tracer, Workflow)
 from repro.core.clustering import VmapClusteringProvider, vmap_signature
 from repro.core.sites import LoadBalancer, Site
 from repro.core.task import FnKeyRegistry, stable_fn_key
@@ -77,13 +77,14 @@ def test_bundle_results_match_per_task_reference():
     assert pool.tasks_run == 24
     assert pool.fused_tasks == 24
     assert pool.fallbacks == 0
-    assert pool.bundles_run < 24
-    assert pool.bundle_stat.peak == 24
-    # measured bundle time attributed per task into the bounded stats
-    assert pool.run_stat.count == 24
-    assert pool.run_stat.total == pytest.approx(pool.device_s)
-    # the service surfaces the pool's metrics on the real path
-    assert svc.metrics()["pool"]["fused_tasks"] == 24
+    assert pool.bundles_run == 1
+    # exact counters: one bundle of all 24, its measured dispatcher time,
+    # no staging (no data layer)
+    m = svc.metrics()["pool"]      # surfaced by the service on this path
+    assert m["bundle_mean"] == 24.0
+    assert m["device_s"] == pool.device_s > 0.0
+    assert m["io_s"] == pool.io_s == 0.0
+    assert m["fused_tasks"] == 24
 
 
 def test_mixed_signatures_form_separate_bundles():
@@ -117,8 +118,10 @@ def test_non_batchable_tasks_run_as_measured_singletons():
     assert [f.get() for f in futs] == [0, 10, 20]
     assert pool.tasks_run == 3
     assert pool.fused_tasks == 0
-    assert pool.run_stat.count == 3
-    assert pool.bundle_stat.peak == 1
+    # each ran alone, measured: three bundles of one
+    assert pool.bundles_run == 3
+    assert pool.metrics()["bundle_mean"] == 1.0
+    assert pool.device_s > 0.0
 
 
 def branchy(x):
@@ -187,6 +190,8 @@ def test_fault_check_fails_one_task_not_the_bundle():
 def test_max_bundle_caps_fuse_width():
     clock = RealClock()
     eng, svc, pool = device_stack(clock, executors=32, max_bundle=4)
+    # each fused bundle emits one `bundle_fused` event valued at its size
+    pool.tracer = Tracer()
     futs = [eng.submit(f"t{i}", body,
                        [np.ones((4,), np.float32),
                         np.ones((4, 4), np.float32)], vmap_key="b")
@@ -194,7 +199,7 @@ def test_max_bundle_caps_fuse_width():
     eng.run()
     svc.shutdown()
     assert all(f.resolved for f in futs)
-    assert pool.bundle_stat.peak <= 4
+    assert max(v for _t, v in pool.tracer.events["bundle_fused"]) <= 4
     assert pool.bundles_run >= 3
 
 
@@ -220,9 +225,10 @@ def test_real_staging_composes_with_bundling():
     eng.run()
     svc.shutdown()
     assert all(f.resolved for f in futs)
-    # staging ran through the pool's measured io path
-    assert pool.io_stat.count == 8
-    assert pool.io_stat.total > 0.0
+    # staging ran through the pool's measured io path, for every task
+    assert pool.tasks_run == 8
+    assert pool.io_s > 0.0
+    assert svc.metrics()["pool"]["io_s"] == pool.io_s
     assert pool.fused_tasks > 0
 
 
