@@ -59,13 +59,34 @@ def resolve_args(task) -> list:
             for a in task.args]
 
 
-def _split_result(results, n: int) -> list:
-    """Un-batch a vmapped call's output pytree into n per-task results."""
-    leaves, treedef = jax.tree_util.tree_flatten(results)
-    if not leaves:
-        return [results] * n
-    return [jax.tree_util.tree_unflatten(treedef, [leaf[i] for leaf in leaves])
-            for i in range(n)]
+def place_outputs(tasks) -> None:
+    """Decide where each task's fused output goes, as its bundle ships
+    (clock thread): it stays on the device (``task.on_device``) when
+    every task that reads it is batchable, as the engine's dependency scan
+    has recorded so far in ``output.consumers_fused``.  Answers no task
+    reads, outputs read by an unbatched body and journaled or durable
+    values go to the host.  A consumer that registers after the ship gets
+    a host value, which stacks as any host input does (DESIGN.md §11)."""
+    for t in tasks:
+        t.on_device = t.output.consumers_fused is True
+
+
+def _fused(fn: Callable, in_axes: tuple):
+    """``jit(vmap(fn))`` that returns one output pytree per task: the
+    unstack runs inside the one program, so a bundle size compiles one
+    program and per-task device values cost no index operation after it.
+    The program keeps the body's name (``jit_<body>``)."""
+    batched = jax.vmap(fn, in_axes=in_axes)
+
+    def split(*args):
+        k = jax.tree_util.tree_leaves(
+            [a for a, ax in zip(args, in_axes) if ax == 0])[0].shape[0]
+        leaves, treedef = jax.tree_util.tree_flatten(batched(*args))
+        return [treedef.unflatten([jax.lax.index_in_dim(x, i, keepdims=False)
+                                   for x in leaves]) for i in range(k)]
+
+    split.__name__ = getattr(batched, "__name__", "fused")
+    return jax.jit(split)
 
 
 def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
@@ -86,17 +107,25 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
     per-task `execute_task`, never to a lost completion; callers count
     the degrade (`fallbacks` in their metrics) so it is never silent.
 
+    Placement (DESIGN.md §11): a fused task whose ``on_device`` is set
+    (`place_outputs`) gets its output as a device `jax.Array`; the others
+    get host values from one `device_get` over their outputs.  A
+    one-task bundle runs its body eagerly and returns what the body
+    returns.
+
     `vmapped_cache` maps ``(stable_fn_key, in_axes)`` to the compiled
-    ``jit(vmap(fn))`` so steady-state bundles pay zero retrace.
+    program (`_fused`), so steady-state bundles pay zero retrace.
 
     Each phase is a span on the profiler's clock (`observability.span`):
-    ``bundle.stack`` (resolving and stacking the arguments; an eager
-    ``jnp.stack`` of device inputs), ``bundle.h2d`` (the copy of a host
-    stack to the device), ``bundle.call`` (the fused call, with a compile
-    on a cache miss), ``bundle.wait`` (until its output is ready),
-    ``bundle.d2h`` (`device_get`), ``bundle.split`` (per-task results),
-    ``bundle.single`` (a one-task bundle's eager body) and
-    ``bundle.fallback`` (the per-task re-run after a fused-path error).
+    ``bundle.stack`` (resolving and stacking the arguments; one jitted
+    stack per argument of device inputs), ``bundle.h2d`` (the copy of a
+    host stack to the device), ``bundle.call`` (the fused call, with a
+    compile on a cache miss), ``bundle.wait`` (until its output is
+    ready), ``bundle.d2h`` (`device_get` of the host-bound outputs, when
+    there are any), ``bundle.split`` (handing each task its output; the
+    device split itself runs inside the fused call), ``bundle.single``
+    (a one-task bundle's eager body) and ``bundle.fallback`` (the
+    per-task re-run after a fused-path error).
     """
     n = len(tasks)
     results: list = [None] * n
@@ -134,16 +163,19 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
         vkey = (stable_fn_key(fn), in_axes)
         vfn = vmapped_cache.get(vkey)
         if vfn is None:
-            vfn = jax.jit(jax.vmap(fn, in_axes=in_axes))
-            vmapped_cache[vkey] = vfn
+            vfn = vmapped_cache[vkey] = _fused(fn, in_axes)
         with span("bundle.call"):
-            out = vfn(*stacked)
+            outs = vfn(*stacked)
         with span("bundle.wait"):
-            jax.block_until_ready(out)
-        with span("bundle.d2h"):
-            out = jax.device_get(out)
+            jax.block_until_ready(outs)
+        host = [j for j, i in enumerate(live) if not tasks[i].on_device]
+        if host:
+            with span("bundle.d2h"):
+                fetched = jax.device_get([outs[j] for j in host])
+            for j, v in zip(host, fetched):
+                outs[j] = v
         with span("bundle.split"):
-            for i, r in zip(live, _split_result(out, len(live))):
+            for i, r in zip(live, outs):
                 results[i] = (True, r, None)
         return results, perf_counter() - t0, len(live), None
     except Exception as err:  # noqa: BLE001 — degrade, counted by callers
@@ -154,15 +186,23 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
         return results, perf_counter() - t0, 0, err
 
 
+@jax.jit
+def _stack_on_device(*items):
+    """Device-resident items of one bundle argument stacked in one program
+    per (K, shape, dtype), not K eager ``expand_dims`` and a
+    ``concatenate``."""
+    return jnp.stack(items)
+
+
 def _stack(items: list):
     """One bundle argument stacked along a new leading axis: host arrays
-    with `np.stack` and one copy to the device (``bundle.h2d``), device
-    arrays with an eager `jnp.stack`."""
+    with `np.stack` and one copy to the device (``bundle.h2d``), anything
+    else (device arrays, or a mix) with one jitted device stack."""
     if all(isinstance(a, np.ndarray) for a in items):
         host = np.stack(items)
         with span("bundle.h2d"):
             return jnp.asarray(host)
-    return jnp.stack(items)
+    return _stack_on_device(*items)
 
 
 def _execute_unchecked(task):
@@ -246,6 +286,7 @@ class VmapClusteringProvider(Provider):
         self.bundles_executed += 1
         self.tasks_executed += len(bundle)
         tasks = [t for t, _ in bundle]
+        place_outputs(tasks)
         results, exec_s, n_fused, fallback = execute_bundle(
             tasks[0].fn, tasks, self._vmapped_cache)
         self.fused_tasks += n_fused

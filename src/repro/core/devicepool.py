@@ -32,11 +32,12 @@ pool is fixed — one device does not grow threads), the data layer's
 time, and `foreach(window=)` / federation operate above the pool seam.
 
 Threading contract (DESIGN.md §10): `submit` and `flush` run on the clock
-thread; dispatcher threads touch only the bundle queue, the vmapped-jit
-cache, and `post`/`release`; completions and all counters run back on the
-clock thread.  The dispatcher reads the clock once per bundle, when it
-takes the bundle off the queue, and hands that time back in the posted
-completion (`Span.dequeued`): it never touches a span itself.
+thread, and place each shipped task's output there; dispatcher threads
+touch only the bundle queue, the vmapped-jit cache, and `post`/`release`;
+completions and all counters run back on the clock thread.  The
+dispatcher reads the clock once per bundle, when it takes the bundle off
+the queue, and hands that time back in the posted completion
+(`Span.dequeued`): it never touches a span itself.
 
 Layer spans on the profiler's clock (`observability.span`): clock thread
 ``pool.submit`` (each `submit` and `flush`) and ``pool.complete`` (one per
@@ -52,7 +53,8 @@ from functools import partial
 from time import perf_counter
 from typing import Any, Callable, Optional
 
-from repro.core.clustering import execute_bundle, resolve_args, vmap_signature
+from repro.core.clustering import (execute_bundle, place_outputs,
+                                   resolve_args, vmap_signature)
 from repro.core.observability import span
 from repro.core.realpool import _require_threadsafe_clock
 from repro.core.simclock import Clock
@@ -84,6 +86,13 @@ class DeviceExecutorPool:
     `last_fallback` keeps the newest such error's repr: on the device path
     a compile refusal or an out-of-memory shows up there, not as silent
     slowness.
+
+    Placement (DESIGN.md §11): as a bucket ships, each task's output is
+    placed (`place_outputs`) — kept on the device when every task that
+    reads it is batchable, fetched to the host otherwise — so a dataflow
+    edge between two fused stages never passes through host memory.
+    `outputs_on_device` and `outputs_to_host` count the fused outputs of
+    each kind (together `fused_tasks`).
     """
 
     autoscale = False
@@ -117,6 +126,8 @@ class DeviceExecutorPool:
         self.tasks_run = 0
         self.bundles_run = 0
         self.fused_tasks = 0
+        self.outputs_on_device = 0    # fused outputs kept as device arrays
+        self.outputs_to_host = 0      # fused outputs fetched to the host
         self.fallbacks = 0
         self.last_fallback: Optional[str] = None
         self.device_s = 0.0   # dispatcher host s inside execute_bundle
@@ -152,7 +163,7 @@ class DeviceExecutorPool:
             bucket.append((task, done, stage))
             if len(bucket) >= self.max_bundle:
                 del self._pending[key]
-                self._q.put(bucket)
+                self._ship(bucket)
             elif not self._flush_scheduled:
                 self._flush_scheduled = True
                 self.clock.schedule(self.linger, self.flush)
@@ -165,7 +176,13 @@ class DeviceExecutorPool:
                 return
             pending, self._pending = self._pending, {}
             for bundle in pending.values():
-                self._q.put(bundle)
+                self._ship(bundle)
+
+    def _ship(self, bundle: list) -> None:
+        """Queue a bucket for the dispatcher, with each task's output
+        placement decided now, on the clock thread (`place_outputs`)."""
+        place_outputs(t for t, _done, _stage in bundle)
+        self._q.put(bundle)
 
     def shutdown(self, wait: bool = True) -> None:
         """Stop the dispatcher threads (after their queued bundles) and
@@ -239,6 +256,12 @@ class DeviceExecutorPool:
             self.bundles_run += 1
             self.device_s += exec_s
             self.fused_tasks += n_fused
+            if n_fused:
+                # a fused task's result is ok; a failed one never ran fused
+                on = sum(1 for (task, _d, _s), res in zip(bundle, out)
+                         if res[0] and task.on_device)
+                self.outputs_on_device += on
+                self.outputs_to_host += n_fused - on
             if fallback is not None:
                 self.fallbacks += 1
                 self.last_fallback = repr(fallback)
@@ -262,7 +285,8 @@ class DeviceExecutorPool:
     def metrics(self) -> dict:
         """Exact counters — safe at any task count.  `device_s` is the
         dispatcher's host seconds inside `execute_bundle`, not device
-        time; `io_s` the staging seconds of every task."""
+        time; `io_s` the staging seconds of every task;
+        `outputs_on_device` + `outputs_to_host` = `fused_tasks`."""
         return {
             "dispatchers": self.size(),
             "tasks_run": self.tasks_run,
@@ -270,6 +294,8 @@ class DeviceExecutorPool:
             "bundle_mean": (self.tasks_run / self.bundles_run
                             if self.bundles_run else 0.0),
             "fused_tasks": self.fused_tasks,
+            "outputs_on_device": self.outputs_on_device,
+            "outputs_to_host": self.outputs_to_host,
             "fallbacks": self.fallbacks,
             "last_fallback": self.last_fallback,
             "device_s": self.device_s,

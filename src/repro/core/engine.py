@@ -300,6 +300,9 @@ class Engine:
             # receives the call args, as on the Procedure path
             if type(inputs) is not tuple:
                 inputs = inputs_of(inputs, *args) if inputs is not None else ()
+            if self.journal is not None or (durable
+                                            and self.restart_log is not None):
+                out.consumers_fused = False    # its value goes to the host
             task = Task(name, fn, args, out, duration, app,
                         self.retry_policy.max_retries, durable, key,
                         inputs=inputs)
@@ -339,7 +342,10 @@ class Engine:
             # dependency scan without per-task garbage: at frontier scale
             # (10^6 in-flight tasks) the list + closure the seed allocated
             # here were ~40% of per-task graph memory; `partial` carries the
-            # task reference in one small object instead
+            # task reference in one small object instead.  It also tells
+            # each argument future whether every task reading it is
+            # batchable (`DataFuture.consumers_fused`, DESIGN.md §11)
+            fused = fn is not None and vmap_key is not None
             first = None
             nfuts = 0
             for a in args:
@@ -347,6 +353,8 @@ class Engine:
                     nfuts += 1
                     if first is None:
                         first = a
+                    if a.consumers_fused is not False:
+                        a.consumers_fused = fused
             if nfuts == 0:
                 if tr is not None:
                     task.path0 = -task.created_time

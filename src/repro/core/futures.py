@@ -38,9 +38,14 @@ class DataFuture:
     # are GC-able) can be observed without retaining the future.  "path" is
     # the critical-path length up to this future (DESIGN.md §12) — always
     # initialized so the traced engine reads it as a plain attribute on
-    # its hot path; only meaningful when a tracer stamps it at completion
+    # its hot path; only meaningful when a tracer stamps it at completion.
+    # "consumers_fused" is what the engine's dependency scan learned of the
+    # tasks that read this future (DESIGN.md §11): None (none yet), True
+    # (every one is batchable: callable + vmap_key) or False (otherwise, or
+    # the value is journaled or written to a restart log); the device pool
+    # keeps a fused output on the device only when it reads True
     __slots__ = ("id", "name", "_value", "_error", "_state", "_callbacks",
-                 "path", "__weakref__")
+                 "path", "consumers_fused", "__weakref__")
 
     PENDING, RESOLVED, FAILED = 0, 1, 2
 
@@ -51,6 +56,7 @@ class DataFuture:
         self._error: BaseException | None = None
         self._state = self.PENDING
         self.path = 0.0
+        self.consumers_fused = None
         # callback storage is shape-polymorphic to keep the per-future
         # footprint small at 10^6-future scale (DESIGN.md §9): None (no
         # callbacks, the transient majority), a bare callable (exactly one

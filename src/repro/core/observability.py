@@ -52,8 +52,9 @@ time.  This module is the one place that question is answered from:
                         ``pool.submit``, ``pool.complete``; dispatcher
                         thread: ``pool.bundle`` around one bundle and, in
                         `clustering.execute_bundle`, ``bundle.stack``,
-                        ``bundle.h2d``, ``bundle.call``, ``bundle.wait``,
-                        ``bundle.d2h``, ``bundle.split``,
+                        ``bundle.h2d`` (host stacks only),
+                        ``bundle.call``, ``bundle.wait``, ``bundle.d2h``
+                        (host-bound outputs only), ``bundle.split``,
                         ``bundle.single``, ``bundle.fallback``.  With the
                         profiler off a span costs one annotation object
                         (0.4–1 µs), so a layer takes one span per call,

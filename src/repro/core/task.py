@@ -123,7 +123,7 @@ class Task:
                  "sim_value", "app", "attempt", "retries_left", "site",
                  "host", "created_time", "submit_time", "start_time",
                  "durable", "fault_check", "_falkon_done", "vmap_key",
-                 "site_failures", "inputs", "span", "path0")
+                 "site_failures", "inputs", "span", "path0", "on_device")
 
     def __init__(self, name: str, fn, args, output: DataFuture,
                  duration: float | None, app: str | None,
@@ -148,6 +148,10 @@ class Task:
         self.durable = durable
         self.fault_check = None
         self.vmap_key = None
+        # where a fused output goes (DESIGN.md §11): a bundling provider
+        # sets it on the clock thread as the bundle ships
+        # (`clustering.place_outputs`); True keeps it on the device
+        self.on_device = False
         # declared file inputs (DataObject tuple) — the data layer's
         # cache-aware dispatch keys on these; empty for compute-only tasks
         self.inputs = inputs

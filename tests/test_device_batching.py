@@ -15,6 +15,7 @@ Covers the acceptance surface of the device-batching PR:
 """
 import gc
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -201,6 +202,115 @@ def test_max_bundle_caps_fuse_width():
     assert all(f.resolved for f in futs)
     assert max(v for _t, v in pool.tracer.events["bundle_fused"]) <= 4
     assert pool.bundles_run >= 3
+
+
+# ---------------------------------------------------------------------------
+# output placement: fused edges stay on the device (DESIGN.md §11)
+# ---------------------------------------------------------------------------
+
+
+def step(x):
+    return jnp.tanh(x * 1.5) + 0.25
+
+
+def np_step(x):
+    # an unbatched (numpy) body: it reads host or device values alike
+    return np.tanh(np.asarray(x) * 1.5) + 0.25
+
+
+def assert_placement_counted(pool):
+    m = pool.metrics()
+    assert m["outputs_on_device"] + m["outputs_to_host"] == m["fused_tasks"]
+
+
+def test_fused_chain_keeps_edges_on_device_and_answers_on_host():
+    clock = RealClock()
+    eng, svc, pool = device_stack(clock, executors=16, max_bundle=16)
+    xs = [np.full((4, 8), 0.1 * i - 0.3, np.float32) for i in range(6)]
+    s1 = [eng.submit(f"a{i}", step, [x], vmap_key="a")
+          for i, x in enumerate(xs)]
+    s2 = [eng.submit(f"b{i}", step, [f], vmap_key="b")
+          for i, f in enumerate(s1)]
+    s3 = [eng.submit(f"c{i}", step, [f], vmap_key="c")
+          for i, f in enumerate(s2)]
+    eng.run()
+    svc.shutdown()
+    # the two edges between fused stages are device arrays, the finals
+    # (read by no task) come back to the host
+    assert all(isinstance(f.get(), jax.Array) for f in s1 + s2)
+    assert all(type(f.get()) is np.ndarray for f in s3)
+    for x, f in zip(xs, s3):
+        want = step(step(step(jnp.asarray(x))))
+        np.testing.assert_allclose(f.get(), np.asarray(want), rtol=1e-6)
+    assert pool.bundles_run == 3 and pool.fused_tasks == 18
+    assert pool.outputs_on_device == 12 and pool.outputs_to_host == 6
+    assert_placement_counted(pool)
+
+
+@pytest.mark.parametrize("why", ["unbatched_reader", "durable", "journaled"])
+def test_fused_outputs_go_to_host_unless_every_reader_is_fused(tmp_path,
+                                                                 why):
+    from repro.core.jobstore import JobStore
+    from repro.core.restart_log import RestartLog
+
+    clock = RealClock()
+    eng, svc, pool = device_stack(clock, executors=16, max_bundle=16)
+    store = None
+    if why == "durable":
+        eng.restart_log = RestartLog(str(tmp_path / "restart.log"))
+    elif why == "journaled":
+        store = JobStore(str(tmp_path / "jobs.db"))
+        eng.journal = store.journal(default_wf="placement")
+    xs = [np.full((4, 8), 0.2 * i, np.float32) for i in range(4)]
+    firsts = [eng.submit(f"a{i}", step, [x], vmap_key="a",
+                         durable=why == "durable")
+              for i, x in enumerate(xs)]
+    if why == "unbatched_reader":
+        # one unbatched reader is enough to send the value to the host
+        eng.submit("join", lambda *v: sum(np_step(a) for a in v), firsts)
+    finals = [eng.submit(f"b{i}", step, [f], vmap_key="b")
+              for i, f in enumerate(firsts)]
+    eng.run()
+    svc.shutdown()
+    if store is not None:
+        store.close()
+    assert all(type(f.get()) is np.ndarray for f in firsts + finals)
+    for x, f in zip(xs, finals):
+        np.testing.assert_allclose(
+            f.get(), np.asarray(step(step(jnp.asarray(x)))), rtol=1e-6)
+    assert pool.fused_tasks == 8 and pool.outputs_on_device == 0
+    assert_placement_counted(pool)
+
+
+@pytest.mark.parametrize("late", ["fused", "unbatched"])
+def test_consumer_registered_after_the_ship_gets_the_right_value(late):
+    clock = RealClock()
+    eng, svc, pool = device_stack(clock, executors=16, max_bundle=16)
+    xs = [np.full((4, 8), 0.3 * i - 0.5, np.float32) for i in range(4)]
+    firsts = [eng.submit(f"a{i}", step, [x], vmap_key="a")
+              for i, x in enumerate(xs)]
+    # only the first output has a (fused) reader when its bundle ships
+    early = eng.submit("early", step, [firsts[0]], vmap_key="b")
+    eng.run()
+    # readers that register after the ship: they get the values as placed
+    if late == "fused":
+        later = [eng.submit(f"b{i}", step, [f], vmap_key="b")
+                 for i, f in enumerate(firsts)]
+    else:
+        later = [eng.submit(f"n{i}", np_step, [f])
+                 for i, f in enumerate(firsts)]
+    eng.run()
+    svc.shutdown()
+    assert isinstance(firsts[0].get(), jax.Array)
+    assert all(type(f.get()) is np.ndarray for f in firsts[1:])
+    for x, f in zip(xs, later):
+        np.testing.assert_allclose(
+            np.asarray(f.get()), np.asarray(step(step(jnp.asarray(x)))),
+            rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(early.get()),
+                               np.asarray(later[0].get()), rtol=1e-6)
+    assert pool.outputs_on_device == 1
+    assert_placement_counted(pool)
 
 
 # ---------------------------------------------------------------------------
