@@ -24,8 +24,8 @@ from repro.core.federation import (FederatedEngine, Mailbox,
                                    hash_partitioner, inputs_partitioner,
                                    skewed_partitioner)
 from repro.core.faults import FaultInjector, RetryPolicy, TaskFailure
-from repro.core.futures import (CompletionCounter, DataFuture, resolved,
-                                when_all)
+from repro.core.futures import (CompletionCounter, DataFuture, Padded,
+                                resolved, when_all)
 from repro.core.health import (METRICS_STREAM_SCHEMA, HealthConfig,
                                HealthMonitor, RollingStat)
 from repro.core.jobstore import (IllegalTransition, JobStore, Journal,
@@ -45,7 +45,7 @@ from repro.core.service import (ResumeView, WorkflowHandle,
                                 WorkflowService)
 from repro.core.simclock import RealClock, SimClock
 from repro.core.sites import LoadBalancer, Site
-from repro.core.task import Task, task_key
+from repro.core.task import Task, Uneven, task_key
 from repro.core.workflow import Procedure, Workflow
 from repro.core.xdtm import (ArrayOf, CSVMapper, Dataset, FILE,
                              FileSystemMapper, FLOAT, INT, ListMapper,
@@ -53,7 +53,8 @@ from repro.core.xdtm import (ArrayOf, CSVMapper, Dataset, FILE,
                              STRING, Struct)
 
 __all__ = [
-    "Engine", "Workflow", "Procedure", "Task", "task_key",
+    "Engine", "Workflow", "Procedure", "Task", "task_key", "Uneven",
+    "Padded",
     "Provider", "WorkerPoolProvider",
     "LocalProvider", "BatchSchedulerProvider", "FalkonProvider",
     "ClusteringProvider", "VmapClusteringProvider",

@@ -28,6 +28,8 @@ amortization.
 """
 from __future__ import annotations
 
+from functools import partial
+from math import prod
 from time import perf_counter
 from typing import Any, Callable
 
@@ -35,11 +37,66 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.futures import DECLARED, Padded
 from repro.core.metrics import StreamStat
 from repro.core.observability import span
 from repro.core.providers import Provider
 from repro.core.simclock import Clock
-from repro.core.task import Task, arg_signature, execute_task, stable_fn_key
+from repro.core.task import (Task, Uneven, arg_signature, execute_task,
+                             extent_of, stable_fn_key)
+
+# the TPU's (8, 128) tile: the first rung of the shape ladder for the
+# second-to-last and last dimension of an uneven value (DESIGN.md §16)
+TILE = (8, 128)
+# what a floating-point value holds past its true extent: padding added as
+# it stacks at its bucket, and whatever a body wrote past its own extent in
+# a padded output.  NaN, so that a body that reads past its extents instead
+# of masking by them poisons its answer (integer values pad with 0)
+PAD_FILL = float("nan")
+
+
+def rung(n: int, tile: int) -> int:
+    """The shape ladder: `n` rounded up to whole tiles, then to the next
+    count of tiles with at most 3 significant bits (1, 2, 3, 4, 5, 6, 7,
+    8, 10, 12, 14, 16, 20, ...), so that a rung is at most 5/4 of the
+    tiles it holds."""
+    u = max(1, -(-int(n) // tile))
+    e = max(0, u.bit_length() - 3)
+    return (-(-u >> e) << e) * tile
+
+
+def bucket(extent) -> tuple:
+    """Shape bucket of the trailing dimensions `extent` of an uneven
+    value: the last on the ladder of 128, the one before it of 8, any
+    others of 1."""
+    tiles = (1,) * max(0, len(extent) - 2) + TILE[-len(extent):]
+    return tuple(rung(n, t) for n, t in zip(extent, tiles))
+
+
+def width(n: int) -> int:
+    """The K ladder: a bundle of `n` tasks of a declared procedure runs at
+    the next power of 4 (1, 4, 16, 64, 256), the rest masked dummy rows."""
+    k = 1
+    while k < n:
+        k *= 4
+    return k
+
+
+def uneven_signature(fn: Callable, decl: Uneven, args: list) -> tuple:
+    """Bundle key of a task whose procedure declares uneven extents: the
+    `vmap_signature` with each uneven argument's trailing dimensions and
+    the own extent rounded up to their shape bucket, so tasks whose
+    extents share a bucket fuse into one program."""
+    sig = list(arg_signature(
+        [a.data if isinstance(a, Padded) else a for a in args]))
+    nd = decl.ndim
+    for p in decl.args:
+        shape, dtype = sig[p]
+        sig[p] = (shape[:len(shape) - nd] + bucket(shape[len(shape) - nd:]),
+                  dtype)
+    own = decl.own_extent(args)
+    return (stable_fn_key(fn), tuple(sig),
+            None if own is None else bucket(own))
 
 
 def vmap_signature(fn: Callable, args: list) -> tuple:
@@ -53,8 +110,13 @@ def vmap_signature(fn: Callable, args: list) -> tuple:
     return (stable_fn_key(fn), arg_signature(args))
 
 
-def resolve_args(task) -> list:
-    """Argument values of a dispatched task (futures are resolved)."""
+def resolve_args(task, padded: bool = False) -> list:
+    """Argument values of a dispatched task (futures are resolved; a
+    `Padded` value comes cropped, or with `padded` as it is held, for a
+    task that declares uneven extents, DESIGN.md §16)."""
+    if padded:
+        return [a.get_padded() if hasattr(a, "get_padded") else a
+                for a in task.args]
     return [a.get() if hasattr(a, "get") and hasattr(a, "on_done") else a
             for a in task.args]
 
@@ -65,17 +127,24 @@ def place_outputs(tasks) -> None:
     every task that reads it is batchable, as the engine's dependency scan
     has recorded so far in ``output.consumers_fused``.  Answers no task
     reads, outputs read by an unbatched body and journaled or durable
-    values go to the host.  A consumer that registers after the ship gets
-    a host value, which stacks as any host input does (DESIGN.md §11)."""
+    values go to the host.  A padded output (of a procedure that declares
+    uneven extents, `Uneven.padded_out`) stays on the device only when
+    every reader declares them too (`DECLARED`); otherwise it goes to the
+    host, cropped (DESIGN.md §16).  A consumer that registers after the
+    ship gets a host value, which stacks as any host input does
+    (DESIGN.md §11), or, of a value kept padded, the value cropped
+    (`DataFuture.get`)."""
     for t in tasks:
-        t.on_device = t.output.consumers_fused is True
+        c = t.output.consumers_fused
+        t.on_device = c is DECLARED or (c is True and (
+            t.uneven is None or not t.uneven.padded_out))
 
 
-def _fused(fn: Callable, in_axes: tuple):
+def _fused(fn: Callable, in_axes: tuple, name: str | None = None):
     """``jit(vmap(fn))`` that returns one output pytree per task: the
     unstack runs inside the one program, so a bundle size compiles one
     program and per-task device values cost no index operation after it.
-    The program keeps the body's name (``jit_<body>``)."""
+    The program keeps the body's name (``jit_<body>``, or `name`)."""
     batched = jax.vmap(fn, in_axes=in_axes)
 
     def split(*args):
@@ -85,7 +154,7 @@ def _fused(fn: Callable, in_axes: tuple):
         return [treedef.unflatten([jax.lax.index_in_dim(x, i, keepdims=False)
                                    for x in leaves]) for i in range(k)]
 
-    split.__name__ = getattr(batched, "__name__", "fused")
+    split.__name__ = name or getattr(batched, "__name__", "fused")
     return jax.jit(split)
 
 
@@ -113,19 +182,35 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
     one-task bundle runs its body eagerly and returns what the body
     returns.
 
-    `vmapped_cache` maps ``(stable_fn_key, in_axes)`` to the compiled
-    program (`_fused`), so steady-state bundles pay zero retrace.
+    Tasks of a procedure that declares uneven extents (``task.uneven``,
+    DESIGN.md §16) share a shape bucket (`uneven_signature`) and take the
+    same path with four steps more: uneven arguments are padded to the
+    bucket as they stack (`_stack_padded`: `np.pad` for host values,
+    inside the device stack for device values); the bundle widens to its
+    rung of the K ladder (`width`) by repeating the first task, whose
+    extra rows are dropped; the true extents go to the body ahead of its
+    arguments as one traced int32 array (`Uneven.extents`); and a padded
+    output is `Padded` where it stays on the device, cropped to its
+    extent where it goes to the host.  Each such task's ``elements`` gets
+    its true and padded element counts.  A one-task bundle of theirs
+    runs fused too, at its bucket.
+
+    `vmapped_cache` maps ``(stable_fn_key, in_axes)`` (declared: and the
+    bucket) to the compiled program (`_fused`), so steady-state bundles
+    pay zero retrace.
 
     Each phase is a span on the profiler's clock (`observability.span`):
     ``bundle.stack`` (resolving and stacking the arguments; one jitted
-    stack per argument of device inputs), ``bundle.h2d`` (the copy of a
-    host stack to the device), ``bundle.call`` (the fused call, with a
-    compile on a cache miss), ``bundle.wait`` (until its output is
+    stack per argument of device inputs), ``bundle.pad`` (an uneven
+    argument's padded stack, inside ``bundle.stack``), ``bundle.h2d`` (the
+    copy of a host stack to the device), ``bundle.call`` (the fused call,
+    with a compile on a cache miss), ``bundle.wait`` (until its output is
     ready), ``bundle.d2h`` (`device_get` of the host-bound outputs, when
-    there are any), ``bundle.split`` (handing each task its output; the
-    device split itself runs inside the fused call), ``bundle.single``
-    (a one-task bundle's eager body) and ``bundle.fallback`` (the
-    per-task re-run after a fused-path error).
+    there are any), ``bundle.crop`` (cutting them to their extents),
+    ``bundle.split`` (handing each task its output; the device split
+    itself runs inside the fused call), ``bundle.single`` (a one-task
+    bundle's eager body) and ``bundle.fallback`` (the per-task re-run
+    after a fused-path error).
     """
     n = len(tasks)
     results: list = [None] * n
@@ -141,7 +226,8 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
         live.append(i)
     if not live:
         return results, 0.0, 0, None
-    if len(live) == 1:
+    decl = tasks[live[0]].uneven
+    if len(live) == 1 and decl is None:
         i = live[0]
         t0 = perf_counter()
         with span("bundle.single"):
@@ -150,31 +236,56 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
     t0 = perf_counter()
     try:
         with span("bundle.stack"):
-            arg_lists = [resolve_args(tasks[i]) for i in live]
-            n_args = len(arg_lists[0])
+            arg_lists = [resolve_args(tasks[i], decl is not None)
+                         for i in live]
+            m, n_args = len(live), len(arg_lists[0])
+            # declared: the bundle runs at its rung of the K ladder, and
+            # an uneven argument always stacks in a bundle of two or
+            # more, so that whether tasks happen to share one never makes
+            # a program
+            k, uneven = (m, ()) if decl is None else (width(m), decl.args)
             # args identical across the bundle broadcast (in_axes=None)
             # instead of being stacked — no 256x weight copies
-            shared = [all(al[i] is arg_lists[0][i] for al in arg_lists)
-                      for i in range(n_args)]
+            shared = [m == 1 or (i not in uneven and all(
+                al[i] is arg_lists[0][i] for al in arg_lists))
+                for i in range(n_args)]
             in_axes = tuple(None if s else 0 for s in shared)
-            stacked = [arg_lists[0][i] if shared[i]
-                       else _stack([al[i] for al in arg_lists])
+            stacked = [_stack_arg([al[i] for al in arg_lists], shared[i],
+                                  k, decl if i in uneven else None)
                        for i in range(n_args)]
-        vkey = (stable_fn_key(fn), in_axes)
+            if decl is not None:
+                own = [decl.own_extent(al) for al in arg_lists]
+                shape = None if own[0] is None else bucket(own[0])
+                ext = np.stack([decl.extents(al) for al in arg_lists])
+                stacked.insert(0, jnp.asarray(np.concatenate(
+                    [ext, np.repeat(ext[:1], k - m, axis=0)])))
+                in_axes = (0,) + in_axes
+        vkey = (stable_fn_key(fn), in_axes) if decl is None else \
+            (stable_fn_key(fn), in_axes, shape)
         vfn = vmapped_cache.get(vkey)
         if vfn is None:
-            vfn = vmapped_cache[vkey] = _fused(fn, in_axes)
+            vfn = vmapped_cache[vkey] = (
+                _fused(fn, in_axes) if decl is None else
+                _fused(_declared_body(fn, decl, shape), in_axes,
+                       getattr(fn, "__name__", None)))
         with span("bundle.call"):
-            outs = vfn(*stacked)
+            outs = vfn(*stacked)[:m]
         with span("bundle.wait"):
             jax.block_until_ready(outs)
         host = [j for j, i in enumerate(live) if not tasks[i].on_device]
         if host:
             with span("bundle.d2h"):
                 fetched = jax.device_get([outs[j] for j in host])
+            if decl is not None and decl.padded_out:
+                with span("bundle.crop"):
+                    fetched = [np.ascontiguousarray(Padded(v, own[j]).crop())
+                               for j, v in zip(host, fetched)]
             for j, v in zip(host, fetched):
                 outs[j] = v
         with span("bundle.split"):
+            if decl is not None:
+                _hand_out_declared(decl, [tasks[i] for i in live], outs,
+                                   arg_lists, own, k)
             for i, r in zip(live, outs):
                 results[i] = (True, r, None)
         return results, perf_counter() - t0, len(live), None
@@ -186,11 +297,134 @@ def execute_bundle(fn: Callable, tasks: list, vmapped_cache: dict):
         return results, perf_counter() - t0, 0, err
 
 
-@jax.jit
-def _stack_on_device(*items):
+def _stack_arg(items: list, shared: bool, k: int, decl: Uneven | None):
+    """One bundle argument: the value itself where the bundle shares it,
+    else stacked along a new leading axis widened to `k` rows by
+    repeating the first.  With `decl` it is an uneven argument, padded to
+    its bucket as it stacks (``bundle.pad``)."""
+    if decl is None:
+        return items[0] if shared else _stack(items + items[:1] * (
+            k - len(items)))
+    with span("bundle.pad"):
+        if shared:
+            return _pad(items[0], decl.ndim)
+        return _stack_padded(items + items[:1] * (k - len(items)),
+                             decl.ndim)
+
+
+def _declared_body(fn: Callable, decl: Uneven, shape) -> Callable:
+    """A declared procedure's body at the bucket `shape`, taking the
+    extents first: ``body(ext, *args)``; with padded outputs, each output
+    element past the task's own extent is set to the fill inside the
+    same program (`_filled_past`)."""
+    if decl.padded_out:
+        return _filled_past(fn, shape, decl.ndim)
+    return partial(fn, shape)
+
+
+def _hand_out_declared(decl: Uneven, tasks: list, outs: list,
+                       arg_lists: list, own: list, k: int) -> None:
+    """Wrap each padded output kept on the device as `Padded` with its
+    extent, and give each task its ``(true, padded)`` element counts
+    (own extent, else its uneven arguments), the bundle's dummy rows
+    shared out over its tasks."""
+    sizes = _elements(decl, arg_lists, own)
+    dummy = (k - len(tasks)) * sizes[0][1] / len(tasks)
+    for j, t in enumerate(tasks):
+        if decl.padded_out and t.on_device:
+            outs[j] = Padded(outs[j], own[j])
+        t.elements = (sizes[j][0], sizes[j][1] + dummy)
+
+
+def _filled_past(fn: Callable, shape: tuple, ndim: int) -> Callable:
+    """The body of a procedure with padded outputs at the bucket `shape`,
+    each output element past the task's own extent (``ext[-1]``) set to
+    the fill (`PAD_FILL`, 0 for integers) inside the same program, so
+    that what a reader finds there never depends on the body."""
+
+    def body(ext, *args):
+        def fill(x):
+            inside = True
+            for d in range(ndim):
+                axis = x.ndim - ndim + d
+                inside = inside & (jax.lax.broadcasted_iota(
+                    jnp.int32, x.shape, axis) < ext[-1, d])
+            return jnp.where(inside, x, jnp.asarray(_fill(x.dtype), x.dtype))
+
+        return jax.tree.map(fill, fn(shape, ext, *args))
+
+    return body
+
+
+def _elements(decl: Uneven, arg_lists: list, own: list) -> list:
+    """``(true, padded)`` element counts of each task: of its own extent
+    where it has one, else summed over its uneven arguments."""
+    out = []
+    for al, e in zip(arg_lists, own):
+        exts = [e] if e is not None else [
+            extent_of(al[p], decl.ndim) for p in decl.args]
+        out.append((sum(prod(x) for x in exts),
+                    sum(prod(bucket(x)) for x in exts)))
+    return out
+
+
+def _bucket_shape(value, ndim: int) -> tuple:
+    """Full shape of an uneven value at its bucket."""
+    data = value.data if isinstance(value, Padded) else value
+    return (tuple(data.shape[:data.ndim - ndim])
+            + bucket(extent_of(value, ndim)))
+
+
+def _pad(value, ndim: int):
+    """One uneven argument at its bucket, unstacked (a one-task bundle):
+    a `Padded` value's data as it is, a host array padded with `np.pad`,
+    a device array padded on the device."""
+    if isinstance(value, Padded):
+        return value.data
+    shape = _bucket_shape(value, ndim)
+    fill = _fill(value.dtype)
+    if isinstance(value, np.ndarray):
+        return np.pad(value, [(0, s - n) for s, n in zip(shape, value.shape)],
+                      constant_values=fill)
+    return _pad_on_device(value, shape=shape, fill=fill)
+
+
+def _stack_padded(items: list, ndim: int):
+    """One uneven bundle argument stacked at its shape bucket: host arrays
+    padded with `np.pad` and copied up once, device values (`Padded`, or
+    arrays at their true extent) padded inside the device stack."""
+    datas = [a.data if isinstance(a, Padded) else a for a in items]
+    shape = _bucket_shape(items[0], ndim)
+    if all(isinstance(a, np.ndarray) for a in datas):
+        fill = _fill(datas[0].dtype)
+        host = np.stack([np.pad(a, [(0, s - n) for s, n in
+                                    zip(shape, a.shape)],
+                                constant_values=fill) for a in datas])
+        with span("bundle.h2d"):
+            return jnp.asarray(host)
+    return _stack_on_device(*datas, shape=shape,
+                            fill=_fill(datas[0].dtype))
+
+
+def _fill(dtype):
+    return PAD_FILL if jnp.issubdtype(dtype, jnp.floating) else 0
+
+
+@partial(jax.jit, static_argnames=("shape", "fill"))
+def _pad_on_device(a, shape, fill):
+    return jnp.pad(a, [(0, s - n) for s, n in zip(shape, a.shape)],
+                   constant_values=fill)
+
+
+@partial(jax.jit, static_argnames=("shape", "fill"))
+def _stack_on_device(*items, shape=None, fill=0.0):
     """Device-resident items of one bundle argument stacked in one program
     per (K, shape, dtype), not K eager ``expand_dims`` and a
-    ``concatenate``."""
+    ``concatenate``.  With `shape`, each item is first padded with `fill`
+    up to it (a no-op for items already at the bucket)."""
+    if shape is not None:
+        items = [jnp.pad(a, [(0, s - n) for s, n in zip(shape, a.shape)],
+                         constant_values=fill) for a in items]
     return jnp.stack(items)
 
 
@@ -211,6 +445,9 @@ def _execute_unchecked(task):
     if fn is None:
         return True, getattr(task, "sim_value", None), None
     try:
+        decl = getattr(task, "uneven", None)
+        if decl is not None:
+            return True, decl.call_exact(fn, resolve_args(task)), None
         return True, fn(*resolve_args(task)), None
     except BaseException as err:  # noqa: BLE001 — engine handles retries
         return False, None, err

@@ -54,7 +54,8 @@ from time import perf_counter
 from typing import Any, Callable, Optional
 
 from repro.core.clustering import (execute_bundle, place_outputs,
-                                   resolve_args, vmap_signature)
+                                   resolve_args, uneven_signature,
+                                   vmap_signature)
 from repro.core.observability import span
 from repro.core.realpool import _require_threadsafe_clock
 from repro.core.simclock import Clock
@@ -128,6 +129,11 @@ class DeviceExecutorPool:
         self.fused_tasks = 0
         self.outputs_on_device = 0    # fused outputs kept as device arrays
         self.outputs_to_host = 0      # fused outputs fetched to the host
+        # tasks of procedures that declare uneven extents (DESIGN.md §16)
+        # and their true / padded element counts, dummy rows included
+        self.padded_tasks = 0
+        self.true_elements = 0
+        self.padded_elements = 0.0
         self.fallbacks = 0
         self.last_fallback: Optional[str] = None
         self.device_s = 0.0   # dispatcher host s inside execute_bundle
@@ -156,7 +162,11 @@ class DeviceExecutorPool:
             if task.vmap_key is None or task.fn is None:
                 self._q.put([(task, done, stage)])
                 return
-            key = (task.vmap_key, vmap_signature(task.fn, resolve_args(task)))
+            decl = task.uneven
+            key = (task.vmap_key,
+                   vmap_signature(task.fn, resolve_args(task))
+                   if decl is None else
+                   uneven_signature(task.fn, decl, resolve_args(task, True)))
             bucket = self._pending.get(key)
             if bucket is None:
                 self._pending[key] = bucket = []
@@ -269,13 +279,22 @@ class DeviceExecutorPool:
                 self.tracer.event("bundle_fused", now, n_fused)
             tr = self.tracer
             self.io_s += sum(io_ss)
+            padded = n_fused and bundle[0][0].uneven is not None
             for (task, done, _stage), (ok, v, err), io_s, run_s in zip(
                     bundle, out, io_ss, run_ss):
                 self.tasks_run += 1
+                if padded and ok:
+                    true, pad = task.elements
+                    self.padded_tasks += 1
+                    self.true_elements += true
+                    self.padded_elements += pad
                 if tr is not None:
                     sp = getattr(task, "span", None)
                     if sp is not None:
                         sp.dequeued = dequeued
+                        if padded and ok:
+                            sp.true_elements, sp.padded_elements = \
+                                task.elements
                     if not ok:
                         # worker-level failure signal (DESIGN.md §13),
                         # same kind the thread/process pools emit
@@ -296,6 +315,9 @@ class DeviceExecutorPool:
             "fused_tasks": self.fused_tasks,
             "outputs_on_device": self.outputs_on_device,
             "outputs_to_host": self.outputs_to_host,
+            "padded_tasks": self.padded_tasks,
+            "true_elements": self.true_elements,
+            "padded_elements": self.padded_elements,
             "fallbacks": self.fallbacks,
             "last_fallback": self.last_fallback,
             "device_s": self.device_s,

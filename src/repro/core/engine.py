@@ -26,7 +26,7 @@ from functools import partial
 
 from repro.core.datastore import inputs_of
 from repro.core.faults import FaultInjector, RetryPolicy, TaskFailure
-from repro.core.futures import DataFuture, when_all
+from repro.core.futures import DECLARED, DataFuture, when_all
 from repro.core.observability import span
 from repro.core.provenance import VDC, InvocationRecord
 from repro.core.providers import (BatchSchedulerProvider, ClusteringProvider,
@@ -254,7 +254,7 @@ class Engine:
     def submit(self, name: str, fn=None, args: list | None = None,
                duration: float | None = None, app: str | None = None,
                durable: bool = False, key: str | None = None,
-               vmap_key=None, inputs=None) -> DataFuture:
+               vmap_key=None, inputs=None, uneven=None) -> DataFuture:
         """Submit one task; returns its output `DataFuture` immediately.
 
         `fn` is the task body (None for pure-simulation tasks); `args` may
@@ -265,6 +265,8 @@ class Engine:
         the result; `inputs` declares the task's file inputs for the data
         layer — a `DataObject`, an iterable of them, or a callable mapping
         the call args to either (see `DataLayer`, DESIGN.md §7).
+        `uneven` is the procedure's declaration of uneven extents
+        (`repro.core.task.Uneven`, DESIGN.md §16).
         """
         with span("engine.submit"):
             args = args or []
@@ -313,6 +315,7 @@ class Engine:
                 # non-terminal transitions never leave the clock thread
                 j.task_submitted(key)
             task.vmap_key = vmap_key
+            task.uneven = uneven
             tr = self.tracer
             if tr is not None:
                 # the sampling decision (Tracer.task_created) is inlined — a
@@ -344,8 +347,11 @@ class Engine:
             # here were ~40% of per-task graph memory; `partial` carries the
             # task reference in one small object instead.  It also tells
             # each argument future whether every task reading it is
-            # batchable (`DataFuture.consumers_fused`, DESIGN.md §11)
+            # batchable, and whether each also declares uneven extents
+            # (`DataFuture.consumers_fused`, DESIGN.md §11, §16)
             fused = fn is not None and vmap_key is not None
+            if fused and uneven is not None:
+                fused = DECLARED
             first = None
             nfuts = 0
             for a in args:
@@ -353,8 +359,11 @@ class Engine:
                     nfuts += 1
                     if first is None:
                         first = a
-                    if a.consumers_fused is not False:
+                    c = a.consumers_fused
+                    if c is None or c is DECLARED:
                         a.consumers_fused = fused
+                    elif c is True and fused is False:
+                        a.consumers_fused = False
             if nfuts == 0:
                 if tr is not None:
                     task.path0 = -task.created_time

@@ -632,7 +632,7 @@ class FederatedEngine:
     def submit(self, name: str, fn=None, args: list | None = None,
                duration: float | None = None, app: str | None = None,
                durable: bool = False, key: str | None = None,
-               vmap_key=None, inputs=None) -> DataFuture:
+               vmap_key=None, inputs=None, uneven=None) -> DataFuture:
         args = args or []
         if key is None:
             key = f"{name}#{self.tasks_submitted}"
@@ -660,7 +660,8 @@ class FederatedEngine:
                     routed[idx] = self._proxy(a, shard)
         out = self.shards[shard].submit(
             name, fn, routed, duration=duration, app=app, durable=durable,
-            key=key, vmap_key=vmap_key, inputs=inputs)
+            key=key, vmap_key=vmap_key, inputs=inputs,
+            uneven=uneven)
         if not out.done:                 # restart-log hits resolve eagerly
             self._owner[out.id] = shard
             out.on_done(self._forget)

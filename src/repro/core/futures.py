@@ -41,9 +41,11 @@ class DataFuture:
     # its hot path; only meaningful when a tracer stamps it at completion.
     # "consumers_fused" is what the engine's dependency scan learned of the
     # tasks that read this future (DESIGN.md §11): None (none yet), True
-    # (every one is batchable: callable + vmap_key) or False (otherwise, or
-    # the value is journaled or written to a restart log); the device pool
-    # keeps a fused output on the device only when it reads True
+    # (every one is batchable: callable + vmap_key), `DECLARED` (every one
+    # is batchable and declares uneven extents, DESIGN.md §16) or False
+    # (otherwise, or the value is journaled or written to a restart log);
+    # the device pool keeps a fused output on the device only when it
+    # reads True or DECLARED, and a padded one only when it reads DECLARED
     __slots__ = ("id", "name", "_value", "_error", "_state", "_callbacks",
                  "path", "consumers_fused", "__weakref__")
 
@@ -101,11 +103,21 @@ class DataFuture:
         self._fire()
 
     def get(self) -> Any:
+        """The resolved value; a `Padded` value comes cropped to its true
+        extent (DESIGN.md §16)."""
         if self._state == self.RESOLVED:
-            return self._value
+            v = self._value
+            return v.crop() if type(v) is Padded else v
         if self._state == self.FAILED:
             raise self._error
         raise FutureError(f"future {self.name or self.id} not resolved")
+
+    def get_padded(self) -> Any:
+        """`get()` without the crop: a `Padded` value as it is held, for
+        a task that declares uneven extents (DESIGN.md §16)."""
+        if self._state == self.RESOLVED:
+            return self._value
+        return self.get()
 
     def on_done(self, cb: Callable[["DataFuture"], None]) -> None:
         if self._state != self.PENDING:
@@ -120,6 +132,59 @@ class DataFuture:
     def __repr__(self):
         st = {0: "pending", 1: "resolved", 2: "failed"}[self._state]
         return f"<Future {self.name or self.id} {st}>"
+
+
+class _Declared:
+    """`DataFuture.consumers_fused` when every task that reads the future
+    so far is batchable and declares uneven extents
+    (`repro.core.task.Uneven`): only such readers may receive a `Padded`
+    value."""
+
+    __slots__ = ()
+
+    def __repr__(self):
+        return "DECLARED"
+
+
+DECLARED = _Declared()
+
+
+class Padded:
+    """A value of uneven extent held at its shape bucket (DESIGN.md §16).
+
+    A fused output of a procedure that declares uneven extents
+    (`repro.core.task.Uneven`) stays on the device at the padded shape its
+    bundle's program produced, when every task that reads it declares
+    uneven extents too: `data` is that array, `extent` the true size of
+    its trailing dimensions.  Only such readers ever receive it
+    (`DataFuture.get_padded`); they get `data` stacked at their bucket and
+    `extent` as a traced argument.  Every other reader — `get()`, a `then`
+    callback, an undeclared task, also one that registers after the
+    bundle shipped — gets `crop()`.
+
+    Example::
+
+        p = Padded(np.zeros((2, 16, 128)), (10, 100))
+        p.crop().shape                 # -> (2, 10, 100)
+    """
+
+    __slots__ = ("data", "extent", "_cropped")
+
+    def __init__(self, data, extent: tuple):
+        self.data = data
+        self.extent = tuple(int(e) for e in extent)
+        self._cropped = None
+
+    def crop(self):
+        """The value at its true extent (a slice of `data`, made on the
+        first call and kept)."""
+        if self._cropped is None:
+            self._cropped = self.data[(Ellipsis,) + tuple(
+                slice(0, e) for e in self.extent)]
+        return self._cropped
+
+    def __repr__(self):
+        return (f"<Padded {tuple(self.data.shape)} extent {self.extent}>")
 
 
 def resolved(value: Any, name: str = "") -> DataFuture:

@@ -155,7 +155,10 @@ class Span:
     after dispatch overhead + staging; on a real pool, the hand-off to
     the pool), `dequeued` (a `DeviceExecutorPool` dispatcher took the
     task's bundle off its queue; 0.0 on other paths), `ended`
-    (completion observed).
+    (completion observed).  `true_elements` / `padded_elements`: for a
+    fused task of a procedure that declares uneven extents (DESIGN.md
+    §16), the elements of its extent and of its shape bucket, with its
+    share of the bundle's dummy rows (0 on other paths).
     `io_s` is the staging (stage-wait) time, `weight` the number of tasks
     this sampled span statistically represents (the sampling stride at
     creation), `shard` the federation shard (None outside a federation).
@@ -163,7 +166,8 @@ class Span:
 
     __slots__ = ("span_id", "name", "app", "shard", "site", "host",
                  "status", "attempt", "weight", "created", "ready",
-                 "submitted", "started", "dequeued", "ended", "io_s")
+                 "submitted", "started", "dequeued", "ended", "io_s",
+                 "true_elements", "padded_elements")
 
     def __init__(self, span_id: str, name: str, app: str | None,
                  shard: int | None, created: float, weight: int):
@@ -183,6 +187,8 @@ class Span:
         self.dequeued = 0.0
         self.ended = 0.0
         self.io_s = 0.0
+        self.true_elements = 0
+        self.padded_elements = 0.0
 
     def queue_wait(self) -> float:
         """Seconds between provider hand-off and body start (dispatch
@@ -198,6 +204,8 @@ class Span:
             "ready": self.ready, "submitted": self.submitted,
             "started": self.started, "dequeued": self.dequeued,
             "ended": self.ended, "io_s": self.io_s,
+            "true_elements": self.true_elements,
+            "padded_elements": self.padded_elements,
         }
 
     def __repr__(self):

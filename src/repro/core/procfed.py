@@ -758,12 +758,18 @@ class ProcessFederation:
     def submit(self, name: str, fn=None, args: list | None = None,
                duration: float | None = None, app: str | None = None,
                durable: bool = False, key: str | None = None,
-               vmap_key=None, inputs=None) -> DataFuture:
+               vmap_key=None, inputs=None, uneven=None) -> DataFuture:
         """Engine-compatible submit.  `fn` and literal args must pickle
         (same contract as `ProcessExecutorPool`); pending-future args are
         encoded as `Ref` markers and resolved cross-process.  `durable`
         and `vmap_key` are accepted for signature compatibility but have
-        no process-shard implementation yet."""
+        no process-shard implementation yet; a declaration of uneven
+        extents (`uneven`) is refused, since a shard would call its body
+        with the wrong arguments."""
+        if uneven is not None:
+            raise NotImplementedError(
+                "process shards do not run procedures that declare "
+                "uneven extents")
         args = args or []
         if key is None:
             key = f"{name}#{self.tasks_submitted}"

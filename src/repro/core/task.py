@@ -14,7 +14,9 @@ import itertools
 import weakref
 from typing import Optional
 
-from repro.core.futures import DataFuture
+import numpy as np
+
+from repro.core.futures import DataFuture, Padded
 
 _task_ids = itertools.count()
 
@@ -100,6 +102,88 @@ def arg_signature(args) -> tuple:
     return tuple(sig)
 
 
+def extent_of(value, ndim: int) -> tuple:
+    """True extent of the trailing `ndim` dimensions of an array value: a
+    `Padded` value's `extent`, else the array's own shape."""
+    if isinstance(value, Padded):
+        return value.extent
+    shape = value.shape
+    return tuple(int(n) for n in shape[len(shape) - ndim:])
+
+
+class Uneven:
+    """Declares that a procedure's array values have uneven extents
+    (DESIGN.md §16), so that the device pool fuses its tasks by shape
+    bucket instead of by exact shape.
+
+    * `args`: positions of the arguments whose trailing `ndim` dimensions
+      vary from task to task;
+    * `extent`: the task's own extent — the true size of the trailing
+      `ndim` dimensions of what the body computes over — as a function of
+      the task's argument values, or the position of an uneven argument
+      whose extent it shares, or None when the task has none of its own;
+    * `padded_out`: the body's output has the own extent in its trailing
+      dimensions, so a fused output is padded and carries its extent
+      (`Padded`) or is cropped to it.
+
+    The body is called as ``fn(shape, ext, *args)``: `shape` is the
+    static shape it computes at (the bucket of the own extent, or the
+    true extent itself when a task runs alone outside a bundle; None when
+    there is no own extent), `ext` an int32 array of shape
+    ``(len(args) + 1, ndim)``, the true extents of the uneven arguments
+    in `args` order and then the own extent (zeros when there is none).
+    Uneven arguments arrive padded past their extent with NaN (0 for
+    integers; `repro.core.clustering.PAD_FILL`): the body must mask
+    everything it reads by `ext`, or its answer is NaN.  Whatever it
+    writes past the own extent of a padded output is replaced by the
+    same fill.
+
+    Example::
+
+        def scale(shape, ext, img, k):
+            h, w = ext[-1, 0], ext[-1, 1]
+            rows = jnp.arange(shape[0])[:, None] < h
+            cols = jnp.arange(shape[1])[None, :] < w
+            return jnp.where(rows & cols, img * k, 0)
+
+        proc = wf.atomic(scale, vmap_key="scale",
+                         uneven=Uneven(args=(0,), extent=0,
+                                       padded_out=True))
+    """
+
+    __slots__ = ("args", "extent", "ndim", "padded_out")
+
+    def __init__(self, args=(), extent=None, ndim: int = 2,
+                 padded_out: bool = False):
+        self.args = tuple(args)
+        self.extent = extent
+        self.ndim = ndim
+        self.padded_out = padded_out
+
+    def own_extent(self, args) -> tuple | None:
+        """The task's own true extent, from its argument values."""
+        e = self.extent
+        if e is None:
+            return None
+        if isinstance(e, int):
+            return extent_of(args[e], self.ndim)
+        return tuple(int(n) for n in e(*args))
+
+    def extents(self, args):
+        """The body's `ext` argument for one task (a numpy int32 array):
+        true extents of the uneven arguments, then the own extent."""
+        own = self.own_extent(args)
+        rows = [extent_of(args[p], self.ndim) for p in self.args]
+        rows.append(own if own is not None else (0,) * self.ndim)
+        return np.asarray(rows, np.int32)
+
+    def call_exact(self, fn, args):
+        """Run the body for one task at true extents: no padding, the
+        static shape is the own extent itself (resolved futures come
+        cropped, `DataFuture.get`)."""
+        return fn(self.own_extent(args), self.extents(args), *args)
+
+
 class Task:
     """One schedulable unit: a lightweight record, not an OS thread.
 
@@ -123,7 +207,8 @@ class Task:
                  "sim_value", "app", "attempt", "retries_left", "site",
                  "host", "created_time", "submit_time", "start_time",
                  "durable", "fault_check", "_falkon_done", "vmap_key",
-                 "site_failures", "inputs", "span", "path0", "on_device")
+                 "site_failures", "inputs", "span", "path0", "on_device",
+                 "uneven", "elements")
 
     def __init__(self, name: str, fn, args, output: DataFuture,
                  duration: float | None, app: str | None,
@@ -152,6 +237,11 @@ class Task:
         # sets it on the clock thread as the bundle ships
         # (`clustering.place_outputs`); True keeps it on the device
         self.on_device = False
+        # a procedure's declaration of uneven extents (`Uneven`, DESIGN.md
+        # §16), and what its fused run padded: ``(true, padded)`` element
+        # counts, written by the dispatcher with the bundle's results
+        self.uneven = None
+        self.elements = None
         # declared file inputs (DataObject tuple) — the data layer's
         # cache-aware dispatch keys on these; empty for compute-only tasks
         self.inputs = inputs
@@ -206,6 +296,9 @@ def execute_task(task):
     try:
         args = [a.get() if hasattr(a, "get") and hasattr(a, "on_done") else a
                 for a in task.args]
+        decl = getattr(task, "uneven", None)
+        if decl is not None:
+            return True, decl.call_exact(fn, args), None
         return True, fn(*args), None
     except BaseException as err:  # noqa: BLE001 - engine handles retries
         return False, None, err

@@ -49,12 +49,17 @@ class Procedure:
     (DESIGN.md §7): a `DataObject`, an iterable of them, or a callable
     mapping the call arguments to either — so a foreach body can name
     per-item files (`inputs=lambda mol: (archive, mol_file[mol])`).
+    `uneven` declares that its array values have uneven extents
+    (`repro.core.task.Uneven`, DESIGN.md §16): the body then takes
+    ``(shape, ext, *args)`` and the device pool fuses its tasks by shape
+    bucket.
     """
 
     def __init__(self, wf: "Workflow", fn: Callable | None, name: str,
                  duration: float | Callable | None = None,
                  app: str | None = None, durable: bool = False,
-                 input_types: tuple = (), vmap_key=None, inputs=None):
+                 input_types: tuple = (), vmap_key=None, inputs=None,
+                 uneven=None):
         self.wf = wf
         self.fn = fn
         self.name = name
@@ -66,6 +71,7 @@ class Procedure:
         self.durable = durable
         self.input_types = input_types
         self.vmap_key = vmap_key
+        self.uneven = uneven
         # materialize non-callable declarations once: a one-shot iterator
         # (generator) would silently yield () on every call after the first
         self.inputs = inputs if inputs is None or callable(inputs) \
@@ -94,7 +100,7 @@ class Procedure:
         return wf.engine.submit(
             self.name, self.fn, list(args), duration=dur, app=self.app,
             durable=self.durable, key=key, vmap_key=self.vmap_key,
-            inputs=inputs)
+            inputs=inputs, uneven=self.uneven)
 
 
 class Workflow:
@@ -155,14 +161,14 @@ class Workflow:
     def atomic(self, fn: Callable | None = None, *, name: str | None = None,
                duration: float | None = None, app: str | None = None,
                durable: bool = False, input_types: tuple = (),
-               vmap_key=None, inputs=None):
+               vmap_key=None, inputs=None, uneven=None):
         """Decorator: define an atomic procedure."""
 
         def wrap(f):
             return Procedure(self, f, name or (f.__name__ if f else "task"),
                              duration=duration, app=app, durable=durable,
                              input_types=input_types, vmap_key=vmap_key,
-                             inputs=inputs)
+                             inputs=inputs, uneven=uneven)
 
         if fn is not None:
             return wrap(fn)
@@ -299,7 +305,7 @@ class Workflow:
                 out.set_error(f._error)
                 return
             try:
-                res = fn(f._value)
+                res = fn(f.get())
             except Exception as err:  # noqa: BLE001
                 out.set_error(err)
                 return
@@ -365,7 +371,7 @@ class Workflow:
             if f.failed or out.done or reduce is None:
                 return                          # first_error is retained
             try:
-                acc_box[0] = reduce(acc_box[0], f._value)
+                acc_box[0] = reduce(acc_box[0], f.get())
             except Exception as err:  # noqa: BLE001 — a raising reducer
                 out.set_error(err)              # fails the join immediately
 
@@ -464,7 +470,7 @@ class _WindowState:
         if f.failed:
             self._fail(f._error)
             return
-        self._deliver(f._value, idx)
+        self._deliver(f.get(), idx)
         self.refill()
 
     def _deliver(self, value, idx) -> None:
