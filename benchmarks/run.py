@@ -30,7 +30,6 @@ MODULES = [
     "benchmarks.app_montage",          # Fig 14
     "benchmarks.app_moldyn",           # Fig 17/18
     "benchmarks.code_size",            # Table 1
-    "benchmarks.vmap_clustering",      # TPU adaptation of clustering
     "benchmarks.device_batching",      # §11: device-batched executor pool
     "benchmarks.roofline",             # §Roofline (from dry-run artifacts)
     "benchmarks.million_tasks",        # scheduler scale (smoke-sized here)

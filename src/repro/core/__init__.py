@@ -10,7 +10,6 @@ Public API:
     Engine, Workflow, Dataset, mappers, FalkonService, providers,
     RestartLog, FaultInjector, SimClock/RealClock.
 """
-from repro.core.clustering import VmapClusteringProvider
 from repro.core.datastore import (DataLayer, DataObject, EvictionPolicy,
                                   ExecutorCache, LFUPolicy, LRUPolicy,
                                   ShardDirectory, SharedStore,
@@ -57,7 +56,7 @@ __all__ = [
     "Padded",
     "Provider", "WorkerPoolProvider",
     "LocalProvider", "BatchSchedulerProvider", "FalkonProvider",
-    "ClusteringProvider", "VmapClusteringProvider",
+    "ClusteringProvider",
     "FalkonService", "FalkonConfig", "DRPConfig",
     "ThreadExecutorPool", "ProcessExecutorPool", "DeviceExecutorPool",
     "DataFuture", "CompletionCounter", "resolved", "when_all",

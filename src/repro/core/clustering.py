@@ -6,25 +6,19 @@ by bundling small jobs.  On an accelerator the analogous per-task cost is
 fuses ready tasks that share a callable and argument shapes into ONE batched
 device call via `jax.vmap` — one launch, one dispatch, full-width compute.
 
-Two consumers share the bundle-execution core in this module
-(`execute_bundle` + `vmap_signature`):
-
-  * `VmapClusteringProvider` — a provider for simulated/engine-driven runs:
-    bundles form on the clock thread and execute inline (works under
-    `SimClock`).
-  * `DeviceExecutorPool` (`repro.core.devicepool`, DESIGN.md §11) — the
-    real pool behind `FalkonService(pool=...)`: bundles execute on a
-    dispatcher thread and measured completions re-enter through
-    `Clock.post_release`.
+The consumer is `DeviceExecutorPool` (`repro.core.devicepool`,
+DESIGN.md §11), the pool behind `FalkonService(pool=...)`: bundles form on
+the clock thread (`bundle_key`, `place_outputs`), execute on a dispatcher
+thread (`execute_bundle`), and measured completions re-enter through
+`Clock.post_release`.
 
 Signature identity is GC-safe: callables are keyed through
 `repro.core.task.stable_fn_key`, never raw ``id(fn)`` — a collected
 callable's address can be reused by a new function, and an id-keyed bundle
 or jit cache would then silently fuse (or run) the wrong callable.
 
-benchmarks/vmap_clustering.py and benchmarks/device_batching.py measure the
-amortization exactly like the paper's Fig 6 measures PBS-overhead
-amortization.
+benchmarks/device_batching.py measures the amortization exactly like the
+paper's Fig 6 measures PBS-overhead amortization.
 """
 from __future__ import annotations
 
@@ -32,19 +26,15 @@ import threading
 from functools import partial
 from math import prod
 from time import perf_counter
-from typing import Any, Callable
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.futures import DECLARED, DataFuture, Held, Padded
-from repro.core.metrics import StreamStat
 from repro.core.observability import span
-from repro.core.providers import Provider
-from repro.core.simclock import Clock
-from repro.core.task import (Task, Uneven, arg_signature, execute_task,
-                             extent_of, stable_fn_key)
+from repro.core.task import Uneven, arg_signature, extent_of, stable_fn_key
 
 # the TPU's (8, 128) tile: the first rung of the shape ladder for the
 # second-to-last and last dimension of an uneven value (DESIGN.md §16)
@@ -678,95 +668,3 @@ def _execute_unchecked(task):
         return True, fn(*resolve_args(task)), None
     except BaseException as err:  # noqa: BLE001 — engine handles retries
         return False, None, err
-
-
-class VmapClusteringProvider(Provider):
-    """Bundle ready tasks with identical (callable, shapes) signatures into a
-    single vmapped execution.  Runs singletons and non-batchable tasks
-    per task; a bundle whose fused call fails also runs per task, and is
-    counted in `fallbacks`.
-
-    Bundles key on the task's user `vmap_key` *and* the structural
-    `vmap_signature` — the signature already embeds the callable's stable
-    identity, so there is exactly one level of keying.  Measured execution
-    seconds are recorded per task into bounded `StreamStat`s (`io_stat`,
-    `run_stat`) with the same meaning as the real pools' metrics
-    (DESIGN.md §10), so singleton fallbacks show up in throughput metrics
-    instead of vanishing.
-    """
-
-    name = "vmap-cluster"
-
-    def __init__(self, clock: Clock, window: float = 0.0,
-                 max_bundle: int = 1024):
-        self.clock = clock
-        self.window = window
-        self.max_bundle = max_bundle
-        self._pending: dict[Any, list] = {}
-        self._flush_scheduled = False
-        self.bundles_executed = 0
-        self.tasks_executed = 0
-        self.fused_tasks = 0
-        self.fallbacks = 0                 # bundles degraded to per-task
-        self._vmapped_cache = Programs()
-        # measured execution seconds per task, same shape as the pool
-        # metrics (io is zero here: no staging path on this provider)
-        self.io_stat = StreamStat(cap=256)
-        self.run_stat = StreamStat(cap=256)
-
-    def submit(self, task: Task, when_done: Callable) -> None:
-        if task.vmap_key is None or task.fn is None:
-            t0 = perf_counter()
-            ok, v, e = execute_task(task)
-            self._observe(perf_counter() - t0)
-            self.tasks_executed += 1
-            when_done(ok, v, e)
-            return
-        key = bundle_key(task)
-        bucket = self._pending.get(key)
-        if bucket is None:
-            self._pending[key] = bucket = []
-        bucket.append((task, when_done))
-        if len(bucket) >= self.max_bundle:
-            self._flush_key(key)
-        elif not self._flush_scheduled:
-            self._flush_scheduled = True
-            self.clock.schedule(self.window, self.flush)
-
-    def _observe(self, run_s: float, io_s: float = 0.0) -> None:
-        now = self.clock.now()
-        self.io_stat.observe(now, io_s)
-        self.run_stat.observe(now, run_s)
-
-    def flush(self):
-        self._flush_scheduled = False
-        for key in list(self._pending):
-            self._flush_key(key)
-
-    def _flush_key(self, key):
-        bundle = self._pending.pop(key, [])
-        if not bundle:
-            return
-        self.bundles_executed += 1
-        self.tasks_executed += len(bundle)
-        tasks = [t for t, _ in bundle]
-        place_outputs(tasks)
-        results, exec_s, n_fused, fallback = execute_bundle(
-            tasks[0].fn, tasks, self._vmapped_cache)
-        self.fused_tasks += n_fused
-        self.fallbacks += fallback is not None
-        per_task = exec_s / max(1, len(bundle))
-        for (t, cb), (ok, v, e) in zip(bundle, results):
-            self._observe(per_task)
-            cb(ok, v, e)
-
-    def metrics(self) -> dict:
-        """Bounded snapshot — safe at any task count."""
-        return {
-            "tasks": self.tasks_executed,
-            "bundles": self.bundles_executed,
-            "fused_tasks": self.fused_tasks,
-            "fallbacks": self.fallbacks,
-            "io_s": self.io_stat.summary(),
-            "run_s": self.run_stat.summary(),
-        }

@@ -22,8 +22,8 @@ Wiring is identical to the other pools::
     eng.run(); svc.shutdown()
 
 Batching contract: a task is *batchable* when it carries both a callable
-and a ``vmap_key`` (the same opt-in `VmapClusteringProvider` uses — the
-user asserts the body is a pure, vmappable JAX function).  Everything else
+and a ``vmap_key`` (the opt-in by which the user asserts the body is a
+pure, vmappable JAX function).  Everything else
 runs as a measured singleton on the dispatcher thread, so mixed workloads
 need no special casing.  Composition with the rest of the stack is
 unchanged: DRP provisioning still sizes the *logical* executor set (the

@@ -318,15 +318,8 @@ class Engine:
             task.uneven = uneven
             tr = self.tracer
             if tr is not None:
-                # the sampling decision (Tracer.task_created) is inlined — a
-                # counter bump and one modulus for the overwhelming non-sampled
-                # majority; ready_t/path0 are stamped in _ready (dependent
-                # tasks) or just below (dependency-free tasks), never in
-                # Task.__init__, so the tracing-off hot path skips the slots
-                tr.tasks_seen = seen = tr.tasks_seen + 1
-                task.span = (tr._new_span(task, task.created_time,
-                                          self.shard_id)
-                             if (seen - 1) % tr._k == 0 else None)
+                task.span = tr.task_created(task, task.created_time,
+                                            self.shard_id)
             if self.fault_injector is not None:
                 inj = self.fault_injector
 
@@ -366,7 +359,7 @@ class Engine:
                         a.consumers_fused = False
             if nfuts == 0:
                 if tr is not None:
-                    task.path0 = -task.created_time
+                    tr.task_ready(task, task.created_time)
                 if (duration is None and fn is not None
                         and self.duration_predictor is not None):
                     task.duration = self.duration_predictor.predict_duration(
@@ -433,47 +426,14 @@ class Engine:
     # ------------------------------------------------------------------
     def _ready(self, task: Task, _f: DataFuture | None = None):
         with span("engine.ready"):
-            tr = self.tracer
-            if tr is None:
-                for a in task.args:
-                    if isinstance(a, DataFuture) and a.failed:
-                        task.output.set_error(
-                            TaskFailure(f"upstream failure for {task.name}"))
-                        self.tasks_failed += 1
-                        if self.journal is not None:
-                            self.journal.task_failed(task.key,
-                                                     "upstream failure")
-                        task.args = ()
-                        return
-            else:
-                # single pass over the args: the upstream-failure check merged
-                # with the O(1)/task critical-path propagation — path up to
-                # this task's start is the max over its parents' path
-                # values, read here *before* the args are cleared below
-                # (DESIGN.md §12)
-                p0 = 0.0
-                for a in task.args:
-                    if type(a) is DataFuture:
-                        if a.failed:
-                            task.output.set_error(TaskFailure(
-                                f"upstream failure for {task.name}"))
-                            self.tasks_failed += 1
-                            tr.task_done(task, self.clock.now(), "failed")
-                            if self.journal is not None:
-                                self.journal.task_failed(task.key,
-                                                         "upstream failure")
-                            task.args = ()
-                            return
-                        p = a.path
-                        if p > p0:
-                            p0 = p
-                now = self.clock.now()
-                # path0 encodes (parent path - ready time): completion adds
-                # `now` back, so the done-path costs one addition per task
-                task.path0 = p0 - now
-                sp = task.span
-                if sp is not None:
-                    sp.ready = now
+            for a in task.args:
+                if type(a) is DataFuture and a.failed:
+                    self._fail(task, TaskFailure(
+                        f"upstream failure for {task.name}"),
+                        "upstream failure")
+                    return
+            if self.tracer is not None:
+                self.tracer.task_ready(task, self.clock.now())
             if task.fn is None and task.vmap_key is None:
                 # pure-sim task: the argument values are never read again, so
                 # drop them now — in a streaming (windowed) expansion this is
@@ -509,12 +469,8 @@ class Engine:
         exist but all are throttled or suspended)."""
         cands = self.balancer.sites_for(task.app)
         if not cands:
-            task.output.set_error(TaskFailure(f"no site for {task.name}"))
-            self.tasks_failed += 1
-            if self.tracer is not None:
-                self.tracer.task_done(task, self.clock.now(), "failed")
-            if self.journal is not None:
-                self.journal.task_failed(task.key, "no site")
+            self._fail(task, TaskFailure(f"no site for {task.name}"),
+                       "no site")
             return True  # consumed (failed), not held
         now = self.clock.now()
         # throttle only matters when there is a choice to steer: with a
@@ -545,16 +501,10 @@ class Engine:
         if self.balancer.duration_aware:
             site.outstanding_work += sim_duration(task)
         site.stats.submitted += 1
-        h = self.health
-        if h is not None:
+        if self.health is not None:
             # before provider.submit: a provider may complete synchronously
-            # and the monitor must see dispatch before finish (inlined
-            # HealthMonitor.task_dispatched — §13 hot-path contract)
-            if not h._armed:
-                h.arm()
-            r = h._running
-            if len(r) < h._track_cap:
-                r.append(task)
+            # and the monitor must see dispatch before finish
+            self.health.task_dispatched(task, now)
         site.provider.submit(
             task, lambda ok, v, e: self._done(task, ok, v, e))
         return True
@@ -644,34 +594,18 @@ class Engine:
             if ok:
                 site.on_success(now - task.submit_time)
                 self.tasks_completed += 1
-                h = self.health
-                if h is not None:
-                    # inlined HealthMonitor.task_finished (§13 hot-path
-                    # contract): error windows come from Site.stats counter
-                    # deltas on the monitor's tick, and the straggler registry
-                    # self-prunes — a success pays only the sampling stride
-                    if h._dur_skip:
-                        h._dur_skip -= 1
-                    else:
-                        h.sample_turnaround(task, site, now)
+                if self.health is not None:
+                    self.health.task_finished(task, site, True, now)
                 self._record(task, "ok")
                 if self.restart_log is not None and task.durable:
                     self.restart_log.append(task.key, value)
                 if self.journal is not None:
                     self.journal.task_done(task.key, value)
-                tr = self.tracer
-                if tr is not None:
-                    # inlined Tracer.task_done: stamp the output's
-                    # critical-path length before the set() fires
-                    # downstream callbacks (dependents read it in _ready)
-                    tr.tasks_done += 1
-                    path = task.path0 + now
-                    if path > tr.critical_path_s:
-                        tr.critical_path_s = path
-                    task.output.path = path
-                    sp = task.span
-                    if sp is not None:
-                        tr._close_span(sp, task, now, "ok")
+                if self.tracer is not None:
+                    # stamp the output's critical-path length before the
+                    # set() fires downstream callbacks (dependents read it
+                    # in _ready)
+                    task.output.path = self.tracer.task_done(task, now, "ok")
                 task.args = ()             # resolved chains must be GC-able: a
                 task.fault_check = None    # retained record must not pin its
                 task.output.set(value)     # upstream futures (DESIGN.md §9)
@@ -698,24 +632,15 @@ class Engine:
             if failures is None:
                 failures = task.site_failures = {}
             failures[site.name] = failures.get(site.name, 0) + 1
-            self._record(task,
-                         "retried" if task.retries_left > 0 else "failed",
+            spent = task.retries_left <= 0
+            self._record(task, "failed" if spent else "retried",
                          error=str(err))
-            tr = self.tracer
-            if tr is not None:
-                status = "retried" if task.retries_left > 0 else "failed"
-                path = tr.task_done(task, now, status)
-                if status == "failed":
-                    task.output.path = path
-            if task.retries_left <= 0:
-                self.tasks_failed += 1
-                if self.journal is not None:
-                    self.journal.task_failed(task.key, str(err))
-                task.args = ()
-                task.fault_check = None
-                task.output.set_error(
-                    err or TaskFailure(f"{task.name} failed"))
+            if spent:
+                self._fail(task, err or TaskFailure(f"{task.name} failed"),
+                           str(err))
                 return
+            if self.tracer is not None:
+                self.tracer.task_done(task, now, "retried")
             task.retries_left -= 1
             task.attempt += 1
             exclude = None
@@ -725,6 +650,20 @@ class Engine:
                 exclude = site.name  # reschedule at a different site
             self.clock.schedule(self.retry_policy.backoff,
                                 partial(self._redispatch, task, exclude))
+
+    def _fail(self, task: Task, err: BaseException, reason: str) -> None:
+        """A task's terminal failure: an upstream failure, no site for its
+        app, or its retries spent.  Its output carries `err`; `reason`
+        goes to the journal."""
+        self.tasks_failed += 1
+        if self.tracer is not None:
+            task.output.path = self.tracer.task_done(
+                task, self.clock.now(), "failed")
+        if self.journal is not None:
+            self.journal.task_failed(task.key, reason)
+        task.args = ()             # resolved chains must be GC-able
+        task.fault_check = None    # (DESIGN.md §9)
+        task.output.set_error(err)
 
     def _redispatch(self, task: Task, exclude_site: str | None) -> None:
         """A retry's dispatch once its backoff has passed (a clock event

@@ -440,12 +440,6 @@ class HealthMonitor:
             self._sink = None
 
     # -- hooks (engine / service hot path) ------------------------------
-    # The engine inlines the bodies of `task_dispatched` / `task_finished`
-    # directly in `_place` / `_done` (same idiom as its inlined
-    # Tracer.task_done) — a bound-method call per task would alone eat
-    # half the 5% overhead budget.  These methods are the reference
-    # implementation and the path for other drivers.
-
     def arm(self) -> None:
         """Start the tick cadence (idempotent; called on the first
         dispatch after an idle period)."""
@@ -472,19 +466,17 @@ class HealthMonitor:
         live object (its `submit_time` is re-stamped on re-placement).
         A success pays the sampling stride counter; every
         `duration_stride`-th success samples its turnaround into the
-        percentile reservoirs (`sample_turnaround`).  Error windows are
+        percentile reservoirs.  Error windows are
         NOT updated here — the tick derives them exactly from
         `Site.stats` counter deltas."""
-        if ok:
-            if self._dur_skip:
-                self._dur_skip -= 1
-            else:
-                self.sample_turnaround(task, site, now)
-
-    def sample_turnaround(self, task, site, now: float) -> None:
-        """The 1-in-`duration_stride` sampled completion: feed the site
-        EWMA / windowed latency percentiles and the per-signature
-        turnaround reservoirs behind straggler thresholds."""
+        if not ok:
+            return
+        if self._dur_skip:
+            self._dur_skip -= 1
+            return
+        # the 1-in-`duration_stride` sampled completion: feed the site
+        # EWMA / windowed latency percentiles and the per-signature
+        # turnaround reservoirs behind straggler thresholds
         self._dur_skip = self._stride - 1
         turnaround = now - task.submit_time
         sh = self._sites.get(site.name)

@@ -76,6 +76,7 @@ from typing import Any, Callable, Optional
 
 from jax.profiler import TraceAnnotation
 
+from repro.core.futures import DataFuture
 from repro.core.health import RollingStat
 from repro.core.metrics import StreamStat, percentile_of
 
@@ -315,15 +316,27 @@ class Tracer:
         self.tasks_seen += 1
         if (self.tasks_seen - 1) % self._k:
             return None
-        return self._new_span(task, now, shard)
-
-    def _new_span(self, task, now: float, shard: int | None) -> "Span":
-        """Materialize the sampled-task span (the engine inlines the
-        counter/modulus fast path and calls this only on a hit)."""
-        span = Span(f"s{self.tasks_seen}", task.name, task.app, shard,
-                    now, self._k)
         self._open_spans += 1
-        return span
+        return Span(f"s{self.tasks_seen}", task.name, task.app, shard,
+                    now, self._k)
+
+    def task_ready(self, task, now: float) -> None:
+        """The task's arguments resolved at `now` (a task with no
+        dependencies is ready when created).  The critical path up to its
+        start is the largest of its parent futures' `path` values; the
+        task keeps it as ``path0 = parent path - now``, so `task_done`
+        adds its end time back in one addition.  Stamps a sampled span's
+        `ready`."""
+        p0 = 0.0
+        for a in task.args:
+            if type(a) is DataFuture:
+                p = a.path
+                if p > p0:
+                    p0 = p
+        task.path0 = p0 - now
+        sp = task.span
+        if sp is not None:
+            sp.ready = now
 
     def task_done(self, task, now: float, status: str = "ok") -> float:
         """Record a task outcome (engine completion path).  Updates exact

@@ -22,8 +22,9 @@ import pytest
 
 from repro.core import (DRPConfig, DataLayer, DeviceExecutorPool,
                         Engine, FalkonConfig, FalkonProvider, FalkonService,
-                        RealClock, SharedStore, SimClock, Tracer, Workflow)
-from repro.core.clustering import VmapClusteringProvider, vmap_signature
+                        LocalProvider, RealClock, SharedStore, SimClock,
+                        Tracer, Workflow)
+from repro.core.clustering import vmap_signature
 from repro.core.sites import LoadBalancer, Site
 from repro.core.task import FnKeyRegistry, stable_fn_key
 from repro.launch.hlo_cost import DeviceModel, DurationPredictor
@@ -148,16 +149,16 @@ def test_failed_fused_call_degrades_per_task_and_is_counted():
     assert svc.metrics()["pool"]["fallbacks"] == pool.fallbacks
 
 
-def test_vmap_provider_counts_failed_fused_calls():
-    eng = Engine(SimClock())
-    prov = VmapClusteringProvider(eng.clock, max_bundle=64)
-    eng.add_site("d", prov, capacity=8)
+def test_pool_counts_failed_fused_calls():
+    eng, svc, pool = device_stack(RealClock(), executors=8, max_bundle=64)
     futs = [eng.submit(f"t{i}", branchy, [np.full((4,), i - 1.5, np.float32)],
                        vmap_key="b") for i in range(4)]
     eng.run()
+    svc.shutdown()
     assert all(f.resolved for f in futs)
-    assert prov.fused_tasks == 0
-    assert prov.metrics()["fallbacks"] == prov.bundles_executed == 1
+    m = pool.metrics()
+    assert m["fused_tasks"] == 0
+    assert m["fallbacks"] == m["bundles_run"] == 1
 
 
 def test_fault_check_fails_one_task_not_the_bundle():
@@ -451,8 +452,8 @@ def test_sim_and_real_scheduling_decisions_match():
                 sites.append((eng.add_site(name, FalkonProvider(svc),
                                            capacity=cap), svc))
             else:
-                prov = VmapClusteringProvider(clock, max_bundle=8)
-                sites.append((eng.add_site(name, prov, capacity=cap), None))
+                sites.append((eng.add_site(name, LocalProvider(clock, cap),
+                                           capacity=cap), None))
         futs = [eng.submit(f"m{i}", body,
                            [np.ones((d,), np.float32),
                             np.ones((d, d), np.float32)], vmap_key="md")
@@ -517,20 +518,20 @@ def test_vmap_signature_distinguishes_shapes_and_callables():
     assert stable_fn_key(f) == stable_fn_key(f)
 
 
-def test_vmap_provider_singleton_fallback_reports_measured_stats():
-    eng = Engine(SimClock())
-    prov = VmapClusteringProvider(eng.clock, max_bundle=64)
-    eng.add_site("d", prov, capacity=8)
+def test_pool_singleton_fallback_reports_measured_stats():
+    eng, svc, pool = device_stack(RealClock(), executors=8, max_bundle=64)
     out = eng.submit("solo", body, [np.ones((4,), np.float32),
                                     np.ones((4, 4), np.float32)],
                      vmap_key="s")
     eng.run()
+    svc.shutdown()
     assert out.resolved
-    # a singleton bundle still lands in the throughput stats instead of
-    # vanishing (same shape as the real pools' metrics)
-    assert prov.run_stat.count == 1
-    assert prov.metrics()["bundles"] == 1
-    assert prov.fused_tasks == 0
+    # a singleton bundle still lands in the measured stats instead of
+    # vanishing: one bundle of one task, run unfused, its time counted
+    m = svc.metrics()["pool"]
+    assert m["tasks_run"] == m["bundles_run"] == 1
+    assert m["fused_tasks"] == 0
+    assert m["device_s"] > 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -783,19 +784,16 @@ def test_declared_bundles_keep_a_padded_output_per_task():
     assert m["args_passed_through"] == 0 and m["stacks_cut"] == 0
 
 
-def test_vmap_provider_buckets_declared_tasks_by_their_held_extents():
-    """`VmapClusteringProvider` keys a declared task's held `Padded`
-    arguments by their buckets, as the device pool does: two stages
-    whose padded outputs land in two buckets run four bundles, none of
-    them degraded."""
+def test_pool_buckets_declared_tasks_by_their_held_extents():
+    """The pool keys a declared task's held `Padded` arguments by their
+    buckets: two stages whose padded outputs land in two buckets run four
+    bundles, none of them degraded."""
     from repro.core import Uneven
 
     def grow(shape, ext, img):
         return img * 2.0
 
-    eng = Engine(SimClock())
-    prov = VmapClusteringProvider(eng.clock, max_bundle=64)
-    eng.add_site("d", prov, capacity=64)
+    eng, svc, pool = device_stack(RealClock(), executors=64, max_bundle=64)
     wf = Workflow("declared", eng)
     decl = Uneven(args=(0,), extent=0, padded_out=True)
     g1 = wf.atomic(grow, vmap_key="g1", uneven=decl)
@@ -804,10 +802,11 @@ def test_vmap_provider_buckets_declared_tasks_by_their_held_extents():
             for s in ((5, 7), (6, 9), (20, 300), (21, 290))]
     finals = [g2(g1(img)) for img in imgs]
     eng.run()
+    svc.shutdown()
     for img, f in zip(imgs, finals):
         np.testing.assert_array_equal(np.asarray(f.get()), 4.0 * img)
-    m = prov.metrics()
-    assert m["fallbacks"] == 0 and m["bundles"] == 4
+    m = pool.metrics()
+    assert m["fallbacks"] == 0 and m["bundles_run"] == 4
     assert m["fused_tasks"] == 8
 
 

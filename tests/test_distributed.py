@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import DRPConfig, Engine, FalkonConfig, FalkonService, SimClock
-from repro.core.clustering import VmapClusteringProvider
+from repro.core import (DeviceExecutorPool, DRPConfig, Engine, FalkonConfig,
+                        FalkonService, RealClock, SimClock)
 from repro.core.engine import FalkonProvider
 from repro.distributed.elastic import ElasticPolicy
 
@@ -68,10 +68,22 @@ def test_drp_shrinks_idle_executors():
     assert len(svc.executors) < n_busy_peak  # idles de-registered
 
 
+def device_engine(executors=64, max_bundle=64):
+    """Engine -> Falkon -> `DeviceExecutorPool`, provisioned so that one
+    scheduler pump dispatches every ready task."""
+    clock = RealClock()
+    pool = DeviceExecutorPool(clock, max_bundle=max_bundle)
+    svc = FalkonService(clock, FalkonConfig(drp=DRPConfig(
+        min_executors=executors, max_executors=executors,
+        alloc_latency=0.0, alloc_chunk=executors)), pool=pool)
+    svc.provision(executors)
+    eng = Engine(clock)
+    eng.add_site("d", FalkonProvider(svc), capacity=executors)
+    return eng, svc, pool
+
+
 def test_vmap_clustering_results_match_per_task():
-    eng_c = Engine(SimClock())
-    prov = VmapClusteringProvider(eng_c.clock, window=0.0, max_bundle=64)
-    eng_c.add_site("d", prov, capacity=64)
+    eng_c, svc, pool = device_engine()
 
     def f(x, w):
         return jnp.tanh(x @ w).sum()
@@ -81,16 +93,15 @@ def test_vmap_clustering_results_match_per_task():
     outs = [eng_c.submit(f"t{i}", f, [xs[i], w], vmap_key="k")
             for i in range(16)]
     eng_c.run()
+    svc.shutdown()
     got = np.array([float(o.get()) for o in outs])
     exp = np.array([float(f(jnp.asarray(xs[i]), w)) for i in range(16)])
     np.testing.assert_allclose(got, exp, rtol=1e-5)
-    assert prov.bundles_executed == 1  # actually fused
+    assert pool.bundles_run == 1 and pool.fused_tasks == 16  # fused
 
 
 def test_vmap_clustering_mixed_signatures_separate_bundles():
-    eng = Engine(SimClock())
-    prov = VmapClusteringProvider(eng.clock, window=0.0, max_bundle=64)
-    eng.add_site("d", prov, capacity=64)
+    eng, svc, pool = device_engine()
 
     def f(x):
         return x * 2
@@ -100,8 +111,9 @@ def test_vmap_clustering_mixed_signatures_separate_bundles():
     b = [eng.submit(f"b{i}", f, [jnp.ones((8,))], vmap_key="b")
          for i in range(4)]
     eng.run()
+    svc.shutdown()
     assert all(o.resolved for o in a + b)
-    assert prov.bundles_executed == 2  # one bundle per signature
+    assert pool.bundles_run == 2  # one bundle per signature
 
 
 def test_grad_compression_in_training_loop():

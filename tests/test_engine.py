@@ -237,3 +237,120 @@ def test_provenance_records_invocations():
     recs = eng.vdc.by_task("stage_b")
     assert len(recs) == 1
     assert recs[0].end_time >= recs[0].start_time >= 0
+
+
+# ---------------------------------------------------------------------------
+# lifecycle hooks: each is its owner's public method, called once a task
+# ---------------------------------------------------------------------------
+
+def test_engine_calls_tracer_and_health_hooks_once_per_task():
+    from collections import Counter
+
+    from repro.core import HealthMonitor, Tracer
+
+    calls = Counter()
+
+    class CountingTracer(Tracer):
+        def task_created(self, task, now, shard=None):
+            calls["task_created"] += 1
+            return super().task_created(task, now, shard)
+
+        def task_ready(self, task, now):
+            calls["task_ready"] += 1
+            super().task_ready(task, now)
+
+        def task_done(self, task, now, status="ok"):
+            calls[f"task_done.{status}"] += 1
+            return super().task_done(task, now, status)
+
+    class CountingMonitor(HealthMonitor):
+        def task_dispatched(self, task, now):
+            calls["task_dispatched"] += 1
+            super().task_dispatched(task, now)
+
+        def task_finished(self, task, site, ok, now):
+            calls[f"task_finished.{ok}"] += 1
+            super().task_finished(task, site, ok, now)
+
+    clock = SimClock()
+    tracer = CountingTracer()
+    eng = Engine(clock, tracer=tracer)
+    CountingMonitor(clock).watch(eng)
+    eng.local_site(concurrency=4)
+    a = eng.submit("a", None, duration=1.0)
+    b = eng.submit("b", None, [a], duration=2.0)
+    c = eng.submit("c", None, [a, b], duration=3.0)
+    eng.run()
+    assert c.resolved
+    assert calls == {"task_created": 3, "task_ready": 3, "task_done.ok": 3,
+                     "task_dispatched": 3, "task_finished.True": 3}
+    # the critical path runs a -> b -> c, ready to done: 1 + 2 + 3 s
+    assert tracer.critical_path_s == pytest.approx(6.0)
+    assert c.path == pytest.approx(6.0)
+    assert [sp.ready for sp in tracer.spans] == [0.0, 1.0, 3.0]
+
+
+class _RecordingJournal:
+    """The journal calls the engine makes with no store behind them."""
+
+    full = False
+
+    def __init__(self):
+        self.failed = []
+
+    def unique_key(self, key):
+        return key
+
+    def task_done(self, key, value):
+        pass
+
+    def task_failed(self, key, reason):
+        self.failed.append((key, reason))
+
+
+@pytest.mark.parametrize("site", ["upstream_traced", "upstream_untraced",
+                                  "no_site", "retries_spent"])
+def test_every_terminal_failure_takes_one_path(site):
+    from repro.core import Tracer
+
+    class Recording(Engine):
+        def _fail(self, task, err, reason):
+            failed.append(task)
+            super()._fail(task, err, reason)
+
+    failed = []
+    clock = SimClock()
+    tracer = None if site == "upstream_untraced" else Tracer()
+    inj = FaultInjector()
+    eng = Recording(clock, tracer=tracer, fault_injector=inj,
+                    retry_policy=RetryPolicy(
+                        max_retries=1 if site == "retries_spent" else 0,
+                        backoff=0.0))
+    journal = eng.journal = _RecordingJournal()
+    if site != "no_site":
+        eng.local_site(concurrency=2)
+    if site == "retries_spent":
+        inj.fail_first_n("t", 2)
+        out = eng.submit("t", None, [1], duration=1.0)
+        reasons = ["injected"]
+    elif site == "no_site":
+        out = eng.submit("t", None, [1], duration=1.0)
+        reasons = ["no site"]
+    else:
+        inj.fail_first_n("up", 1)
+        up = eng.submit("up", None, [1], duration=1.0)
+        out = eng.submit("t", None, [up], duration=1.0)
+        reasons = ["injected", "upstream failure"]
+    eng.run()
+    assert out.failed
+    with pytest.raises(TaskFailure):
+        out.get()
+    assert failed[-1].output is out
+    assert len(failed) == len(reasons) == eng.tasks_failed
+    for t, (key, reason), want in zip(failed, journal.failed, reasons):
+        assert key == t.key and want in reason
+        assert t.args == () and t.fault_check is None
+        assert t.output.failed
+    if tracer is not None:
+        assert tracer.tasks_failed == len(reasons)
+        assert tracer.tasks_retried == (1 if site == "retries_spent" else 0)
